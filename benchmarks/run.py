@@ -6,6 +6,7 @@ Run:  PYTHONPATH=src python -m benchmarks.run [--only fig2,table1,...]
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 
@@ -27,6 +28,7 @@ def main() -> None:
         "perf_dsekl": perf_dsekl.run,
     }
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -36,8 +38,11 @@ def main() -> None:
                 print(row, flush=True)
         except Exception as e:  # pragma: no cover
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"{name}/_suite_seconds,{(time.time()-t0)*1e6:.0f},done",
               flush=True)
+    if failed:
+        sys.exit(f"benchmark suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

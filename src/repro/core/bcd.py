@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dsekl import DSEKLConfig
-from repro.distributed.compat import shard_map
 from repro.kernels.dsekl import ops as kops
 
 Array = jax.Array
@@ -265,7 +264,7 @@ def make_mesh_bcd_ops(cfg: DSEKLConfig, mesh, *, data_axis: str = "data",
     def _acc_body(xi, yi, xj, f_loc, idx, mask, gb):
         return gb + _acc_tile(cfg, xi, yi, xj, f_loc[idx], mask)[None]
 
-    acc = jax.jit(shard_map(
+    acc = jax.jit(jax.shard_map(
         _acc_body, mesh=mesh,
         in_specs=(P(data_axis, None), P(data_axis), P(), P(data_axis),
                   P(), P(), P(data_axis, None, None)),
@@ -275,7 +274,7 @@ def make_mesh_bcd_ops(cfg: DSEKLConfig, mesh, *, data_axis: str = "data",
     def _fupd_body(xi, xj, delta, f_loc, idx, mask):
         return f_loc.at[idx].add(_fupd_tile(cfg, xi, xj, delta, mask))
 
-    fupd = jax.jit(shard_map(
+    fupd = jax.jit(jax.shard_map(
         _fupd_body, mesh=mesh,
         in_specs=(P(data_axis, None), P(), P(), P(data_axis), P(), P()),
         out_specs=P(data_axis), check_vma=False))
@@ -289,7 +288,7 @@ def make_mesh_bcd_ops(cfg: DSEKLConfig, mesh, *, data_axis: str = "data",
         safe = jnp.where((local >= 0) & (local < rows_m), local, rows_m)
         return alpha_loc.at[safe].add(delta)
 
-    scatter = jax.jit(shard_map(
+    scatter = jax.jit(jax.shard_map(
         _scatter_body, mesh=mesh,
         in_specs=(P(model_axis), P(), P()), out_specs=P(model_axis),
         check_vma=False))

@@ -39,7 +39,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import dsekl, losses as losses_lib, sampler
 from repro.core.dsekl import DSEKLConfig
 from repro.distributed import compression
-from repro.distributed.compat import shard_map
 from repro.kernels.dsekl import ops as kops
 
 Array = jax.Array
@@ -236,7 +235,7 @@ def make_distributed_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     """
     body = functools.partial(_local_step, cfg, n_global,
                              data_axis=data_axis, model_axis=model_axis)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
                   P(model_axis), P(model_axis), P(), P()),
@@ -299,7 +298,7 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     if precondition:
         body = functools.partial(_local_block_step_precond, cfg, n_global,
                                  data_axis=data_axis, model_axis=model_axis)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
                       P(model_axis), P(model_axis), P(model_axis), P(), P(),
@@ -329,7 +328,7 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
 
     body = functools.partial(_local_block_step, cfg, n_global,
                              data_axis=data_axis, model_axis=model_axis)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
                   P(model_axis), P(model_axis), P(model_axis), P(), P()),
@@ -424,7 +423,7 @@ def make_mesh_eval(cfg: DSEKLConfig, mesh: Mesh, model_axis: str = "model",
                                     impl=cfg.impl)
         return jax.lax.psum(f_part, model_axis)
 
-    mapped = jax.jit(shard_map(
+    mapped = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(model_axis, None), P(model_axis)),
         out_specs=P(), check_vma=False))

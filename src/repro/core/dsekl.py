@@ -22,6 +22,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import losses as losses_lib
 from repro.core import sampler
@@ -632,8 +633,13 @@ def predict_labels(f: Array) -> Array:
 
 
 def support_vectors(alpha: Array, tol: float = 1e-8) -> Array:
-    """Indices with non-negligible dual weight (truncation as in §5)."""
-    return jnp.nonzero(jnp.abs(alpha) > tol)[0]
+    """Indices with non-negligible dual weight (truncation as in §5).
+
+    Found on the host from one O(N) copy of alpha: the count is
+    data-dependent, so a device ``nonzero`` cannot be compiled ahead and
+    runs eagerly at every build."""
+    keep = np.abs(np.asarray(alpha)) > tol
+    return jnp.asarray(np.flatnonzero(keep), jnp.int32)
 
 
 def truncate(alpha: Array, x_train: Array, tol: float = 1e-8

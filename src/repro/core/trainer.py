@@ -1004,6 +1004,7 @@ def fit_loop(plan: ExecutionPlan, key: Array, *, n_epochs: int = 50,
                       + (" — already converged" if converged else ""))
     sub = None
     hook_stop = False
+    prev_alpha = np.asarray(state.alpha, np.float64)
     if start < n_epochs:
         key, sub = jax.random.split(key)
         plan.plan_epoch(sub)
@@ -1014,7 +1015,6 @@ def fit_loop(plan: ExecutionPlan, key: Array, *, n_epochs: int = 50,
             plan.plan_epoch(sub_next)           # one epoch ahead
         else:
             sub_next = None
-        prev_alpha = state.alpha
         t0 = time.perf_counter()
         state = plan.run_epoch(state, sub)
         if truncate_every and (e + 1) % truncate_every == 0:
@@ -1022,7 +1022,11 @@ def fit_loop(plan: ExecutionPlan, key: Array, *, n_epochs: int = 50,
                 alpha=_truncate_smallest(state.alpha, truncate_frac))
         state.alpha.block_until_ready()
         dt = time.perf_counter() - t0
-        delta = float(jnp.linalg.norm(state.alpha - prev_alpha))
+        # |dalpha| on a host copy: a fixed-order float64 reduction, so the
+        # history does not depend on how alpha is sharded.
+        alpha_host = np.asarray(state.alpha, np.float64)
+        delta = float(np.sqrt(np.sum(np.square(alpha_host - prev_alpha))))
+        prev_alpha = alpha_host
         converged = delta < tol                 # paper §4.2 stopping rule
         rec: Dict[str, Any] = {"epoch": e + 1, "delta_alpha": delta,
                                "seconds": dt}

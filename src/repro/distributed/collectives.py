@@ -23,8 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
-
 Array = jax.Array
 
 
@@ -37,7 +35,7 @@ def allgather_matmul_overlapped(x: Array, w: Array, axis: str) -> Array:
     full (N*m_loc, k) activation; w (k, n) is replicated over ``axis``.
     Returns the FULL (N*m_loc, n) product, assembled ring-step by ring-step
     (block i computed as soon as shard i arrives)."""
-    n_dev = compat.axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     m_loc = x.shape[0]
     out = jnp.zeros((n_dev * m_loc, w.shape[-1]), x.dtype)
@@ -60,7 +58,7 @@ def ring_psum_matmul(x: Array, w: Array, axis: str) -> Array:
     """Inside shard_map: x (m, k_loc) and w (k_loc, n) are matching shards
     of a contraction dim sharded over ``axis``.  Returns the full (m, n)
     sum on every device via a ring all-reduce of the partial products."""
-    n_dev = compat.axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     partial = jnp.einsum("mk,kn->mn", x, w).astype(jnp.float32)
     acc = partial
     for _ in range(n_dev - 1):              # unrolled: each hop overlappable

@@ -20,7 +20,7 @@ This generalizes ``rbf_block.py`` (the original RBF-only path) in two ways:
 
    * ``dual_pass_pallas``  — v given up front.  One (ni, nj) sweep; f is
      accumulated into a revisited output block over the inner j axis, and
-     the per-i-block partial g rows land in an (ni, J) output summed
+     the per-i-block partial g rows land in an (ni, 1, J) output summed
      outside the kernel (each block written exactly once — no revisit
      hazards on the g output).
    * ``train_pass_pallas`` — v computed *inside* from the loss gradient
@@ -57,7 +57,20 @@ Array = jax.Array
 BLOCK_I = 128
 BLOCK_J = 128
 
-VMEM_BUDGET = 8 * 1024 * 1024   # bytes of VMEM we allow one tile set
+# Bytes of VMEM one kernel's tile set may use: the TPU's default scoped
+# VMEM limit is 16 MiB (v5e), less headroom for the compiler's own
+# temporaries.  tests/test_tpu_compile.py holds the choosers to it.
+VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def tile_vmem_bytes(bi: int, bj: int, d: int) -> int:
+    """VMEM of one (bi, bj) tile set as the TPU lays it out: the (b, D)
+    row tiles pad D to 128 lanes and every (b, 1) vector to a full 128-lane
+    row, both double-buffered by the pipeline; about four (bi, bj) f32
+    temporaries live at once (cross term, distances, K, and the split
+    operands of the f32-precision MXU product)."""
+    d_pad = -(-d // 128) * 128
+    return 4 * (2 * (bi + bj) * (d_pad + 128) + 4 * bi * bj)
 
 
 def choose_blocks(n_i: int, n_j: int, d: int):
@@ -66,8 +79,7 @@ def choose_blocks(n_i: int, n_j: int, d: int):
     bj = 256 if n_j >= 256 else BLOCK_J
     bi = 1024
     while bi > 128:
-        need = 4 * (bi * d + bj * d + bi * bj + bi + bj)
-        if need <= VMEM_BUDGET:
+        if tile_vmem_bytes(bi, bj, d) <= VMEM_BUDGET:
             break
         bi //= 2
     return max(bi, 128), bj
@@ -97,8 +109,7 @@ def choose_predict_blocks(n_q: int, n_sv: int, d: int):
     bs = 256 if n_sv >= 256 else BLOCK_J
     bq = min(2048, max(128, -(-n_q // 128) * 128))
     while bq > 128:
-        need = 4 * (bq * d + bs * d + bq * bs + bq + bs)
-        if need <= VMEM_BUDGET:
+        if tile_vmem_bytes(bq, bs, d) <= VMEM_BUDGET:
             break
         bq //= 2
     return max(bq, 128), bs
@@ -118,13 +129,20 @@ def predict_hbm_bytes(n_q: int, n_sv: int, d: int, block_q: int,
 # accumulation) — norms and the nonlinearity stay f32.
 # ---------------------------------------------------------------------------
 
+def _dot(a: Array, b: Array, contract) -> Array:
+    """MXU contraction with f32 accumulation.  f32 operands request full
+    f32 precision explicitly (no silent bf16 passes); bf16 operands are the
+    opt-in fast path."""
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(a, b, dimension_numbers=(contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _cross_term(xi: Array, xj: Array, mxu_dtype) -> Array:
     """xi @ xj^T on the MXU with f32 accumulation, (bi, bj)."""
-    return jax.lax.dot_general(
-        xi.astype(mxu_dtype), xj.astype(mxu_dtype),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    return _dot(xi.astype(mxu_dtype), xj.astype(mxu_dtype), ((1,), (1,)))
 
 
 def _sq_dists_tile(xi: Array, xj: Array, mxu_dtype) -> Array:
@@ -136,16 +154,29 @@ def _sq_dists_tile(xi: Array, xj: Array, mxu_dtype) -> Array:
 
 def _l1_dists_tile(xi: Array, xj: Array) -> Array:
     """sum_d |xi_d - xj_d| without the (bi, bj, D) broadcast: a fori_loop
-    over features keeps VMEM at O(bi*bj) (VPU work, no MXU form exists)."""
+    over features keeps VMEM at O(bi*bj) (VPU work, no MXU form exists).
+
+    Mosaic lowers no dynamic slice along the lane (feature) axis, so
+    feature k is extracted by a masked lane reduction instead.  Features
+    go in static 128-lane chunks so each extraction costs one vreg column
+    whatever D is; the accumulation order is still k = 0..D-1."""
     bi, d = xi.shape
     bj = xj.shape[0]
+    acc = jnp.zeros((bi, bj), jnp.float32)
+    for c0 in range(0, d, 128):
+        xic, xjc = xi[:, c0:c0 + 128], xj[:, c0:c0 + 128]
+        lane_i = jax.lax.broadcasted_iota(jnp.int32, xic.shape, 1)
+        lane_j = jax.lax.broadcasted_iota(jnp.int32, xjc.shape, 1)
 
-    def body(k, acc):
-        ci = jax.lax.dynamic_slice_in_dim(xi, k, 1, axis=1)     # (bi, 1)
-        cj = jax.lax.dynamic_slice_in_dim(xj, k, 1, axis=1)     # (bj, 1)
-        return acc + jnp.abs(ci - cj.T)
+        def body(k, acc, xic=xic, xjc=xjc, lane_i=lane_i, lane_j=lane_j):
+            ci = jnp.sum(jnp.where(lane_i == k, xic, 0.0), axis=1,
+                         keepdims=True)                         # (bi, 1)
+            cj = jnp.sum(jnp.where(lane_j == k, xjc, 0.0), axis=1,
+                         keepdims=True)                         # (bj, 1)
+            return acc + jnp.abs(ci - cj.T)
 
-    return jax.lax.fori_loop(0, d, body, jnp.zeros((bi, bj), jnp.float32))
+        acc = jax.lax.fori_loop(0, xic.shape[1], body, acc)
+    return acc
 
 
 def _tile_rbf(xi, xj, mxu_dtype, *, gamma: float = 1.0):
@@ -230,9 +261,7 @@ def _matvec_kernel(xi_ref, xj_ref, a_ref, o_ref, *, tile_fn):
 
     k = tile_fn(xi_ref[...].astype(jnp.float32),
                 xj_ref[...].astype(jnp.float32))        # (bi, bj)
-    o_ref[...] += jax.lax.dot_general(
-        k, a_ref[...], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o_ref[...] += _dot(k, a_ref[...], ((1,), (0,)))
 
 
 def _vecmat_kernel(xj_ref, xi_ref, v_ref, o_ref, *, tile_fn):
@@ -244,9 +273,7 @@ def _vecmat_kernel(xj_ref, xi_ref, v_ref, o_ref, *, tile_fn):
 
     k = tile_fn(xi_ref[...].astype(jnp.float32),
                 xj_ref[...].astype(jnp.float32))        # (bi, bj)
-    o_ref[...] += jax.lax.dot_general(
-        k, v_ref[...], dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o_ref[...] += _dot(k, v_ref[...], ((0,), (0,)))
 
 
 def kernel_matvec_pallas(x: Array, z: Array, a: Array, *,
@@ -318,13 +345,18 @@ def _dual_kernel(xi_ref, xj_ref, a_ref, v_ref, f_ref, gp_ref, *, tile_fn):
 
     k = tile_fn(xi_ref[...].astype(jnp.float32),
                 xj_ref[...].astype(jnp.float32))        # (bi, bj), ONCE
-    f_ref[...] += jax.lax.dot_general(                  # f_i += K @ a_j
-        k, a_ref[...], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    gj = jax.lax.dot_general(                           # g partial: K^T @ v_i
-        k, v_ref[...], dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # (bj, 1)
+    f_ref[...] += _dot(k, a_ref[...], ((1,), (0,)))    # f_i += K @ a_j
+    gj = _dot(k, v_ref[...], ((0,), (0,)))   # g partial K^T @ v_i, (bj, 1)
     gp_ref[...] = gj.T                                  # (1, bj), written once
+
+
+def _g_parts_shape(ni: int, j_pad: int) -> jax.ShapeDtypeStruct:
+    """The partial-g output: one (1, J_pad) row per i block.  The unit
+    middle axis makes each (1, block_j) block span the array's full
+    second-to-last dimension, which is what the TPU lowering requires of a
+    block whose sublane extent is not a multiple of 8; the i axis is
+    squeezed out of the block (``None``)."""
+    return jax.ShapeDtypeStruct((ni, 1, j_pad), jnp.float32)
 
 
 def dual_pass_pallas(x: Array, z: Array, a: Array, v: Array, *,
@@ -335,10 +367,11 @@ def dual_pass_pallas(x: Array, z: Array, a: Array, v: Array, *,
                      interpret: bool = False):
     """(f, g) = (K @ a, K^T @ v) with each K tile evaluated once.
 
-    The g output is materialized as (n_i_blocks, J) partial rows — O(ni * J)
-    floats, tiny next to the O(I*J) block — and summed outside the kernel so
-    every output block is written exactly once (no non-consecutive output
-    revisits, which the TPU grid does not guarantee to accumulate)."""
+    The g output is materialized as (n_i_blocks, 1, J) partial rows —
+    O(ni * J) floats, tiny next to the O(I*J) block — and summed outside
+    the kernel so every output block is written exactly once (no
+    non-consecutive output revisits, which the TPU grid does not guarantee
+    to accumulate)."""
     tile_fn = make_tile_fn(kernel_name, params or {}, mxu_dtype)
     n_i, d = x.shape
     n_j = z.shape[0]
@@ -357,15 +390,15 @@ def dual_pass_pallas(x: Array, z: Array, a: Array, v: Array, *,
         ],
         out_specs=[
             pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_j), lambda i, j: (i, j)),
+            pl.BlockSpec((None, 1, block_j), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
-            jax.ShapeDtypeStruct((ni, zp.shape[0]), jnp.float32),
+            _g_parts_shape(ni, zp.shape[0]),
         ],
         interpret=interpret,
     )(xp, zp, ap, vp)
-    return f_out[:n_i, 0], jnp.sum(g_parts, axis=0)[:n_j]
+    return f_out[:n_i, 0], jnp.sum(g_parts, axis=(0, 1))[:n_j]
 
 
 # ---------------------------------------------------------------------------
@@ -389,9 +422,7 @@ def _train_kernel(xi_ref, xj_ref, a_ref, y_ref, f_ref, gp_ref,
         k = tile_fn(xi_ref[...].astype(jnp.float32),
                     xj_ref[...].astype(jnp.float32))    # (bi, bj), ONCE
         kbuf[j] = k                                     # stash for the g sweep
-        facc[...] += jax.lax.dot_general(
-            k, a_ref[...], dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        facc[...] += _dot(k, a_ref[...], ((1,), (0,)))
 
         @pl.when(j == nj - 1)
         def _loss():
@@ -407,9 +438,7 @@ def _train_kernel(xi_ref, xj_ref, a_ref, y_ref, f_ref, gp_ref,
     @pl.when(p == 1)
     def _g_sweep():
         k = kbuf[j]                                     # replay, no recompute
-        gj = jax.lax.dot_general(
-            k, vbuf[...], dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bj, 1)
+        gj = _dot(k, vbuf[...], ((0,), (0,)))          # (bj, 1)
         gp_ref[...] = gj.T
 
 
@@ -422,7 +451,8 @@ def train_pass_blocks(n_i: int, n_j: int, d: int):
     jp = -(-n_j // bj) * bj
     bi = 512
     while bi >= 128:
-        need = 4 * (bi * jp + bi * d + bj * d + 2 * bi + bj)
+        # + the K row-block scratch and the f / v scratch columns.
+        need = tile_vmem_bytes(bi, bj, d) + 4 * bi * (jp + 2 * 128)
         if need <= VMEM_BUDGET:
             return bi, bj
         bi //= 2
@@ -465,11 +495,14 @@ def train_pass_pallas(x: Array, z: Array, a: Array, y: Array,
         ],
         out_specs=[
             pl.BlockSpec((block_i, 1), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((1, block_j), lambda i, p, j: (i, j)),
+            # Phase 0 parks on block (i, 0, 0), which phase 1 writes first,
+            # so each partial-g block is visited in one consecutive run and
+            # written back once.
+            pl.BlockSpec((None, 1, block_j), lambda i, p, j: (i, 0, j * p)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
-            jax.ShapeDtypeStruct((ni, zp.shape[0]), jnp.float32),
+            _g_parts_shape(ni, zp.shape[0]),
         ],
         scratch_shapes=[
             pltpu.VMEM((nj, block_i, block_j), jnp.float32),
@@ -478,4 +511,4 @@ def train_pass_pallas(x: Array, z: Array, a: Array, y: Array,
         ],
         interpret=interpret,
     )(xp, zp, ap, yp)
-    return f_out[:n_i, 0], jnp.sum(g_parts, axis=0)[:n_j]
+    return f_out[:n_i, 0], jnp.sum(g_parts, axis=(0, 1))[:n_j]

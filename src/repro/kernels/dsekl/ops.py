@@ -62,7 +62,8 @@ def resolve_impl(impl: str, kernel_name: str) -> str:
     a private helper.  ``"auto"`` honours the ``REPRO_IMPL`` env override
     (the CI backend matrix — read at trace time, set it before the process
     compiles anything), then picks ``pallas`` on TPU for kernels with a
-    fused tile and ``ref`` everywhere else.
+    fused tile and ``ref`` everywhere else.  An explicit Pallas backend
+    for a kernel with no tile raises instead of running XLA in its place.
     """
     if impl == "auto":
         impl = os.environ.get("REPRO_IMPL", "auto") or "auto"
@@ -73,7 +74,8 @@ def resolve_impl(impl: str, kernel_name: str) -> str:
         on_tpu = jax.default_backend() == "tpu"
         impl = "pallas" if (on_tpu and kernel_name in _pk.TILE_FNS) else "ref"
     if impl in ("pallas", "pallas_interpret") and kernel_name not in _pk.TILE_FNS:
-        impl = "ref"
+        raise ValueError(f"impl={impl!r}: no Pallas tile for kernel "
+                         f"{kernel_name!r}; available: {sorted(_pk.TILE_FNS)}")
     return impl
 
 

@@ -8,24 +8,17 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the jax version has it (added after 0.4.x;
-    older releases raise AttributeError on ``jax.sharding.AxisType``)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """(16, 16) data x model single pod; (2, 16, 16) pod x data x model for
     the 2-pod = 512-chip deployment."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly forced-host) devices exist;
     used by tests and CPU examples."""
-    return jax.make_mesh((data, model), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -54,6 +54,7 @@ import numpy as np       # noqa: E402
 
 from repro.configs import get_config                        # noqa: E402
 from repro.distributed.sharding import MeshCtx              # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 from repro.launch.mesh import make_local_mesh, make_production_mesh  # noqa: E402
 from repro.models.model import LanguageModel                # noqa: E402
 from repro.serving import ServingEngine                     # noqa: E402
@@ -367,6 +368,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.tenants and args.online:
         ap.error("--tenants fronts the one-shot engine mode; for a "

@@ -35,6 +35,7 @@ from repro.checkpoint import CheckpointManager                 # noqa: E402
 from repro.configs import get_config                           # noqa: E402
 from repro.data.pipeline import BigramPipeline                 # noqa: E402
 from repro.distributed.sharding import MeshCtx, make_rules     # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 from repro.launch.mesh import make_local_mesh, make_production_mesh  # noqa: E402
 from repro.models.model import LanguageModel                   # noqa: E402
 from repro.nn.module import param_pspecs                       # noqa: E402
@@ -217,6 +218,7 @@ def main():
                          "mesh (where prefetch also hides the per-shard H2D "
                          "transfers)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.dsekl:
         train_dsekl(args)

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed import compat
 from repro.distributed.sharding import MeshCtx
 from repro.models import layers
 from repro.nn.module import Param
@@ -159,7 +158,7 @@ def moe_forward(params, cfg: ModelConfig, ctx: MeshCtx, x: Array,
         dp = tuple(ctx.data_axes)
         body = functools.partial(
             _moe_inner, cfg, e_loc, cap, dp, ctx.model_axis, tokens_sharded)
-        y = compat.shard_map(
+        y = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(tokens_rule, None) if tokens_sharded else P(None, None),
                       tok_spec, tok_spec,

@@ -84,7 +84,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import dsekl
 from repro.core.dsekl import DSEKLConfig
-from repro.distributed.compat import shard_map
 from repro.kernels.dsekl import ops as kops
 
 Array = jax.Array
@@ -220,7 +219,7 @@ class DSEKLPredictionEngine:
             # psum of |query_block| floats over the data axis.
             return jax.lax.psum(local_f(xq, xs, a), axis)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             sharded_f, mesh=self.mesh,
             in_specs=(P(None, None), P(axis, None), P(axis)),
             out_specs=P(),
@@ -247,7 +246,7 @@ class DSEKLPredictionEngine:
         if self.mesh is None:
             return jax.jit(local_k)
         axis = ec.data_axis
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_k, mesh=self.mesh,
             in_specs=(P(None, None), P(axis, None)),
             out_specs=P(None, axis),        # K tile sharded like the SVs

@@ -48,16 +48,15 @@ _SCRIPT = textwrap.dedent("""
         allgather_matmul_overlapped, ring_psum_matmul)
     from repro.distributed.compression import compressed_psum
 
-    from repro.launch.mesh import _mesh_kwargs
-    from repro.distributed.compat import shard_map
-    mesh = jax.make_mesh((8,), ("x",), **_mesh_kwargs(1))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     k1, k2, k3 = jax.random.split(key, 3)
 
     # --- allgather matmul: x row-sharded, w replicated ------------------
     x = jax.random.normal(k1, (64, 32))
     w = jax.random.normal(k2, (32, 16))
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda xs, ws: allgather_matmul_overlapped(xs, ws, "x"),
         mesh=mesh, in_specs=(P("x", None), P(None, None)),
         out_specs=P(None, None), check_vma=False))(x, w)
@@ -67,7 +66,7 @@ _SCRIPT = textwrap.dedent("""
     # --- ring psum matmul: contraction sharded --------------------------
     xc = jax.random.normal(k1, (16, 64))
     wc = jax.random.normal(k2, (64, 24))
-    got2 = jax.jit(shard_map(
+    got2 = jax.jit(jax.shard_map(
         lambda xs, ws: ring_psum_matmul(xs, ws, "x"),
         mesh=mesh, in_specs=(P(None, "x"), P("x", None)),
         out_specs=P(None, None), check_vma=False))(xc, wc)
@@ -78,7 +77,7 @@ _SCRIPT = textwrap.dedent("""
     g = jax.random.normal(k3, (8, 256))   # row per device
     def body(gs, key):
         return compressed_psum(gs[0], "x", key, bits=8)
-    got3 = jax.jit(shard_map(
+    got3 = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("x", None), P()),
         out_specs=P(None), check_vma=False))(g, jax.random.PRNGKey(1))
     want3 = jnp.sum(g, axis=0)

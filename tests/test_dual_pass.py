@@ -181,7 +181,8 @@ def test_train_pass_blocks_budget():
     assert got is not None
     bi, bj = got
     jp = -(-2048 // bj) * bj
-    assert 4 * (bi * jp + bi * 64 + bj * 64 + 2 * bi + bj) <= block.VMEM_BUDGET
+    assert (block.tile_vmem_bytes(bi, bj, 64) + 4 * bi * (jp + 2 * 128)
+            <= block.VMEM_BUDGET)
     assert block.train_pass_blocks(4096, 1 << 20, 64) is None
 
 

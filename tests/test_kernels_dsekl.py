@@ -89,11 +89,12 @@ def test_bf16_mxu_path_accuracy():
 
 
 def test_choose_blocks_vmem_budget():
+    from repro.kernels.dsekl.block import tile_vmem_bytes
     from repro.kernels.dsekl.rbf_block import (choose_blocks, pass_hbm_bytes,
                                                VMEM_BUDGET)
     for d in [54, 128, 512, 2048]:
         bi, bj = choose_blocks(8192, 8192, d)
-        assert 4 * (bi * d + bj * d + bi * bj + bi + bj) <= VMEM_BUDGET
+        assert tile_vmem_bytes(bi, bj, d) <= VMEM_BUDGET
         # Larger bi must never increase the traffic model.
         assert pass_hbm_bytes(8192, 8192, d, bi, bj) <= \
             pass_hbm_bytes(8192, 8192, d, 128, 128)
@@ -109,6 +110,16 @@ def test_ops_dispatch_ref_on_cpu():
         np.asarray(kops.kernel_matvec(x, z, a)),
         np.asarray(ref.ref_kernel_matvec(kern, x, z, a)),
         rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
+def test_resolve_impl_explicit_pallas_without_tile_raises(impl, monkeypatch):
+    """An explicit Pallas backend never quietly becomes the XLA path."""
+    monkeypatch.delenv("REPRO_IMPL", raising=False)
+    assert kops.resolve_impl(impl, "rbf") == impl
+    assert kops.resolve_impl("auto", "rbf") == "ref"        # CPU
+    with pytest.raises(ValueError, match="no Pallas tile"):
+        kops.resolve_impl(impl, "no_such_kernel")
 
 
 def test_ops_nonrbf_falls_back():

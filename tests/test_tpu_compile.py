@@ -1,0 +1,123 @@
+"""Compile the DSEKL Pallas kernels for a described TPU v5e chip.
+
+Nothing runs: each test lowers and compiles one kernel at a deployment
+width (D = 54, the covertype shape; D = 784, the MNIST shape) for a v5e
+that is described, not attached, and asserts the Pallas kernel survived
+into the program (``tpu_custom_call``).  This is what catches, at no chip
+time, what interpret mode cannot: block shapes the TPU lowering refuses,
+primitives Mosaic does not lower, and tile sets over the scoped VMEM limit.
+
+Block sizes come from the same choosers the ops use
+(``choose_blocks`` / ``train_pass_blocks`` / ``choose_predict_blocks``).
+The topology is described inside a module-scoped fixture, never at import:
+only the worker that runs this file loads the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import dsekl, losses
+from repro.kernels.dsekl import block
+
+WIDTHS = [54, 784]
+I_TRAIN = J_TRAIN = 4096          # a large sampled block / J union
+N_QUERY, N_SV = 1024, 65536        # one serving query block x support set
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        from jax.experimental import topologies
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                  # no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # A compile for a described chip cannot be read back from the
+        # persistent cache; keep it out of the cache.
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "the Pallas kernel was not compiled in"
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_train_pass_compiles(one_chip, d):
+    bi, bj = block.train_pass_blocks(I_TRAIN, J_TRAIN, d)
+    grad = losses.get_loss("hinge").grad_f
+    _compile(lambda x, z, a, y: block.train_pass_pallas(
+        x, z, a, y, grad, block_i=bi, block_j=bj),
+        _f32(one_chip, I_TRAIN, d), _f32(one_chip, J_TRAIN, d),
+        _f32(one_chip, J_TRAIN), _f32(one_chip, I_TRAIN))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_dual_pass_compiles(one_chip, d):
+    bi, bj = block.choose_blocks(I_TRAIN, J_TRAIN, d)
+    _compile(lambda x, z, a, v: block.dual_pass_pallas(
+        x, z, a, v, block_i=bi, block_j=bj),
+        _f32(one_chip, I_TRAIN, d), _f32(one_chip, J_TRAIN, d),
+        _f32(one_chip, J_TRAIN), _f32(one_chip, I_TRAIN))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_matvec_training_blocks_compile(one_chip, d):
+    bi, bj = block.choose_blocks(I_TRAIN, J_TRAIN, d)
+    _compile(lambda x, z, a: block.kernel_matvec_pallas(
+        x, z, a, block_i=bi, block_j=bj),
+        _f32(one_chip, I_TRAIN, d), _f32(one_chip, J_TRAIN, d),
+        _f32(one_chip, J_TRAIN))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_matvec_serving_blocks_compile(one_chip, d):
+    bq, bs = block.choose_predict_blocks(N_QUERY, N_SV, d)
+    _compile(lambda x, z, a: block.kernel_matvec_pallas(
+        x, z, a, block_i=bq, block_j=bs),
+        _f32(one_chip, N_QUERY, d), _f32(one_chip, N_SV, d),
+        _f32(one_chip, N_SV))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_vecmat_compiles(one_chip, d):
+    bj, bi = block.choose_blocks(J_TRAIN, I_TRAIN, d)   # g resident: big J
+    _compile(lambda x, z, v: block.kernel_vecmat_pallas(
+        x, z, v, block_i=bi, block_j=bj),
+        _f32(one_chip, I_TRAIN, d), _f32(one_chip, J_TRAIN, d),
+        _f32(one_chip, I_TRAIN))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_laplacian_tile_compiles(one_chip, d):
+    bi, bj = block.train_pass_blocks(I_TRAIN, J_TRAIN, d)
+    grad = losses.get_loss("hinge").grad_f
+    _compile(lambda x, z, a, y: block.train_pass_pallas(
+        x, z, a, y, grad, kernel_name="laplacian", block_i=bi, block_j=bj),
+        _f32(one_chip, I_TRAIN, d), _f32(one_chip, J_TRAIN, d),
+        _f32(one_chip, J_TRAIN), _f32(one_chip, I_TRAIN))
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_gradient_step_core_compiles(one_chip, d):
+    """The jitted block-gradient core of a training step (what every plan
+    runs), at n_grad = n_expand = 1024, reaches the fused train pass."""
+    cfg = dsekl.DSEKLConfig(n_grad=1024, n_expand=1024, impl="pallas")
+    _compile(lambda xi, yi, xj, aj: dsekl.grad_block(cfg, xi, yi, xj, aj),
+             _f32(one_chip, 1024, d), _f32(one_chip, 1024),
+             _f32(one_chip, 1024, d), _f32(one_chip, 1024))
