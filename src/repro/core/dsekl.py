@@ -470,18 +470,25 @@ def step_serial(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
     correction after the dual pass.
     """
     n = x.shape[0]
-    ki, kj = jax.random.split(key)
-    idx_i = sampler.sample_uniform(ki, n, cfg.n_grad)
-    idx_j = sampler.sample_uniform(kj, n, cfg.n_expand)
+    with jax.named_scope("dsekl.sample"):
+        ki, kj = jax.random.split(key)
+        idx_i = sampler.sample_uniform(ki, n, cfg.n_grad)
+        idx_j = sampler.sample_uniform(kj, n, cfg.n_expand)
 
-    xi, yi = x[idx_i], y[idx_i]
-    xj, aj = x[idx_j], state.alpha[idx_j]
+    with jax.named_scope("dsekl.gather"):
+        xi, yi = x[idx_i], y[idx_i]
+        xj, aj = x[idx_j], state.alpha[idx_j]
 
     if pc is None:
-        g = grad_block(cfg, xi, yi, xj, aj, scale_n(cfg, n))
-        return apply_update(cfg, state, idx_j, g)
-    g, delta = grad_block_precond(cfg, xi, yi, xj, aj, pc, scale_n(cfg, n))
-    return apply_update_precond(cfg, state, idx_j, g, pc.indices, delta)
+        with jax.named_scope("dsekl.train_pass"):
+            g = grad_block(cfg, xi, yi, xj, aj, scale_n(cfg, n))
+        with jax.named_scope("dsekl.update"):
+            return apply_update(cfg, state, idx_j, g)
+    with jax.named_scope("dsekl.train_pass"):
+        g, delta = grad_block_precond(cfg, xi, yi, xj, aj, pc,
+                                      scale_n(cfg, n))
+    with jax.named_scope("dsekl.update"):
+        return apply_update_precond(cfg, state, idx_j, g, pc.indices, delta)
 
 
 # ---------------------------------------------------------------------------
@@ -497,18 +504,24 @@ def _parallel_inner(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
     Thin in-memory wrapper over the block-parametrized core.
     """
     n = x.shape[0]
-    xi, yi = x[idx_i], y[idx_i]
-    xjk = x[idx_jk]                     # (K, j, D)
-    ajk = state.alpha[idx_jk]           # (K, j)
+    with jax.named_scope("dsekl.gather"):
+        xi, yi = x[idx_i], y[idx_i]
+        xjk = x[idx_jk]                 # (K, j, D)
+        ajk = state.alpha[idx_jk]       # (K, j)
     flat_j = idx_jk.reshape(-1)
 
     if pc is None:
-        flat_g = grad_block_parallel(cfg, xi, yi, xjk, ajk, scale_n(cfg, n))
-        return apply_update_parallel(cfg, state, flat_j, flat_g)
-    flat_g, delta = grad_block_parallel_precond(cfg, xi, yi, xjk, ajk, pc,
-                                                scale_n(cfg, n))
-    return apply_update_parallel_precond(cfg, state, flat_j, flat_g,
-                                         pc.indices, delta)
+        with jax.named_scope("dsekl.train_pass"):
+            flat_g = grad_block_parallel(cfg, xi, yi, xjk, ajk,
+                                         scale_n(cfg, n))
+        with jax.named_scope("dsekl.update"):
+            return apply_update_parallel(cfg, state, flat_j, flat_g)
+    with jax.named_scope("dsekl.train_pass"):
+        flat_g, delta = grad_block_parallel_precond(cfg, xi, yi, xjk, ajk,
+                                                    pc, scale_n(cfg, n))
+    with jax.named_scope("dsekl.update"):
+        return apply_update_parallel_precond(cfg, state, flat_j, flat_g,
+                                             pc.indices, delta)
 
 
 def epoch_parallel(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
@@ -520,19 +533,22 @@ def epoch_parallel(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
     """
     n = x.shape[0]
     state = state._replace(epoch=state.epoch + 1)
-    ki, kj = jax.random.split(key)
-    i_batches = sampler.epoch_batches(ki, n, cfg.n_grad)          # (Bi, i)
-    j_batches = sampler.epoch_batches(kj, n, cfg.n_expand)        # (Bj, j)
-    n_i = i_batches.shape[0]
-    n_j = j_batches.shape[0]
-    k = min(cfg.n_workers, n_j)
-    # Assign K expansion batches to each I-batch, cycling through the epoch's
-    # J-partition without replacement.
-    assign = (jnp.arange(n_i)[:, None] * k + jnp.arange(k)[None, :]) % n_j
+    with jax.named_scope("dsekl.sample"):
+        ki, kj = jax.random.split(key)
+        i_batches = sampler.epoch_batches(ki, n, cfg.n_grad)      # (Bi, i)
+        j_batches = sampler.epoch_batches(kj, n, cfg.n_expand)    # (Bj, j)
+        n_i = i_batches.shape[0]
+        n_j = j_batches.shape[0]
+        k = min(cfg.n_workers, n_j)
+        # Assign K expansion batches to each I-batch, cycling through the
+        # epoch's J-partition without replacement.
+        assign = (jnp.arange(n_i)[:, None] * k
+                  + jnp.arange(k)[None, :]) % n_j
 
     def body(st, ib_and_assign):
         idx_i, a = ib_and_assign
-        idx_jk = j_batches[a]                                     # (K, j)
+        with jax.named_scope("dsekl.sample"):
+            idx_jk = j_batches[a]                                 # (K, j)
         return _parallel_inner(cfg, st, x, y, idx_i, idx_jk, pc), ()
 
     state, _ = jax.lax.scan(body, state, (i_batches, assign))

@@ -24,6 +24,7 @@ through ``checkpoint.CheckpointManager``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -87,6 +88,7 @@ def _resolve_preconditioner(cfg: DSEKLConfig, precondition, data,
         cfg, data, jax.random.fold_in(key, _PRECOND_KEY_TAG), k=k)
 
 
+@functools.partial(jax.profiler.annotate_function, name="dsekl.fit")
 def fit(cfg: DSEKLConfig, x, y=None, key: Array = None, *,
         execution: Optional[str] = None, algorithm: str = "serial",
         n_epochs: int = 50, tol: float = 1e-3,
@@ -156,87 +158,94 @@ def fit(cfg: DSEKLConfig, x, y=None, key: Array = None, *,
     fit after that boundary's snapshot.  A live appendable source
     (``data.RingSource``) is snapshotted once at entry: the fit trains
     a frozen, versioned window while the writer keeps appending.
+
+    Under ``jax.profiler.trace`` the call is the host span ``dsekl.fit``;
+    its argument checks, preconditioner and plan building are
+    ``dsekl.fit.setup``, and ``fit_loop`` adds its own spans (epochs and
+    their phases; docs/OPERATIONS.md, "Tracing a fit").
     """
-    if key is None:
-        raise TypeError("fit() requires a PRNG key (jax.random.PRNGKey)")
-    if x_val is not None and y_val is None:
-        raise TypeError(
-            "fit() got x_val without y_val: validation labels are required "
-            "to evaluate (pass y_val, or drop x_val to skip eval)")
-    source = None
-    if hasattr(x, "gather") and hasattr(x, "n"):        # any DataSource
-        if y is not None:
+    with jax.profiler.TraceAnnotation("dsekl.fit.setup"):
+        if key is None:
+            raise TypeError("fit() requires a PRNG key (jax.random.PRNGKey)")
+        if x_val is not None and y_val is None:
             raise TypeError(
-                "fit() over a DataSource takes labels from the source; "
-                "pass y=None (a separate y would be silently wrong)")
-        if hasattr(x, "snapshot") and hasattr(x, "append"):
-            # A live appendable source (RingSource): fit trains over a
-            # frozen, versioned snapshot of the current window — the
-            # writer keeps appending, this fit's indices never move.
-            # (The online service owns the grow-across-epochs loop;
-            # a plain fit is one frozen window.)
-            x = x.snapshot()
-        source = x
-        x = y = None
-    hosted_data = source is not None and not isinstance(source,
-                                                        InMemorySource)
-    execution = trainer.resolve_execution(execution, cfg,
-                                          algorithm=algorithm,
-                                          hosted_data=hosted_data,
-                                          mesh=mesh)
-    if execution in ("serial", "parallel"):
-        algorithm = execution                   # the backend IS the algorithm
-        if isinstance(source, InMemorySource):
-            x, y = source.x, source.y
-        elif source is not None:
-            raise ValueError(
-                f"execution={execution!r} needs device-resident data; a "
-                "HostSource trains out of core via 'hosted' or 'mesh'")
-        n = int(x.shape[0])
-    else:
-        if source is None:                      # raw arrays -> host mirror
-            source = InMemorySource(x, y)
-        n = source.n
-    if eval_cache == "auto":
-        eval_cache = (execution in ("serial", "parallel")
-                      and x_val is not None
-                      and 4 * int(x_val.shape[0]) * n
-                      <= _EVAL_CACHE_BUDGET_BYTES)
-    manager = None
-    if checkpoint_dir is not None:
-        from repro.checkpoint import CheckpointManager
-        manager = CheckpointManager(checkpoint_dir, keep=checkpoint_keep)
-    if execution == "bcd" and truncate_every:
-        raise ValueError(
-            "execution='bcd' cannot truncate: zeroing alpha entries "
-            "outside a round would desync the incremental residual "
-            "f = K alpha that the block solves maintain")
-    pre = _resolve_preconditioner(cfg, precondition,
-                                  source if source is not None else x, key,
-                                  manager=manager, resume=resume)
-    if execution == "bcd" and pre is not None:
-        raise ValueError(
-            "execution='bcd' solves each block exactly — EigenPro "
-            "preconditioning applies to the stochastic step only (drop "
-            "precondition/cfg.precondition_k)")
-    snapshot_extra = {"precond": pre.to_extra()} if pre is not None else None
-    if (pre is not None and cfg.precondition_auto_lr
-            and cfg.schedule == "const"):
-        # The step-size rule wants the per-step J-union size: how many
-        # expansion coordinates one step scatters.
-        if execution == "mesh" and mesh is not None:
-            n_model = dict(zip(mesh.axis_names,
-                               mesh.devices.shape)).get("model", 1)
-            j_union = n_model * cfg.n_expand
-        elif algorithm == "parallel":
-            j_union = cfg.n_workers * cfg.n_expand
+                "fit() got x_val without y_val: validation labels are required "
+                "to evaluate (pass y_val, or drop x_val to skip eval)")
+        source = None
+        if hasattr(x, "gather") and hasattr(x, "n"):        # any DataSource
+            if y is not None:
+                raise TypeError(
+                    "fit() over a DataSource takes labels from the source; "
+                    "pass y=None (a separate y would be silently wrong)")
+            if hasattr(x, "snapshot") and hasattr(x, "append"):
+                # A live appendable source (RingSource): fit trains over a
+                # frozen, versioned snapshot of the current window — the
+                # writer keeps appending, this fit's indices never move.
+                # (The online service owns the grow-across-epochs loop;
+                # a plain fit is one frozen window.)
+                x = x.snapshot()
+            source = x
+            x = y = None
+        hosted_data = source is not None and not isinstance(source,
+                                                            InMemorySource)
+        execution = trainer.resolve_execution(execution, cfg,
+                                              algorithm=algorithm,
+                                              hosted_data=hosted_data,
+                                              mesh=mesh)
+        if execution in ("serial", "parallel"):
+            algorithm = execution                   # the backend IS the algorithm
+            if isinstance(source, InMemorySource):
+                x, y = source.x, source.y
+            elif source is not None:
+                raise ValueError(
+                    f"execution={execution!r} needs device-resident data; a "
+                    "HostSource trains out of core via 'hosted' or 'mesh'")
+            n = int(x.shape[0])
         else:
-            j_union = cfg.n_expand
-        cfg = cfg.replace(lr0=pre.step_size(j_union))
-    with trainer.make_plan(execution, cfg, x=x, y=y, source=source,
-                           algorithm=algorithm, prefetch=prefetch,
-                           eval_cache=eval_cache, mesh=mesh,
-                           precond=pre) as plan:
+            if source is None:                      # raw arrays -> host mirror
+                source = InMemorySource(x, y)
+            n = source.n
+        if eval_cache == "auto":
+            eval_cache = (execution in ("serial", "parallel")
+                          and x_val is not None
+                          and 4 * int(x_val.shape[0]) * n
+                          <= _EVAL_CACHE_BUDGET_BYTES)
+        manager = None
+        if checkpoint_dir is not None:
+            from repro.checkpoint import CheckpointManager
+            manager = CheckpointManager(checkpoint_dir, keep=checkpoint_keep)
+        if execution == "bcd" and truncate_every:
+            raise ValueError(
+                "execution='bcd' cannot truncate: zeroing alpha entries "
+                "outside a round would desync the incremental residual "
+                "f = K alpha that the block solves maintain")
+        pre = _resolve_preconditioner(cfg, precondition,
+                                      source if source is not None else x, key,
+                                      manager=manager, resume=resume)
+        if execution == "bcd" and pre is not None:
+            raise ValueError(
+                "execution='bcd' solves each block exactly — EigenPro "
+                "preconditioning applies to the stochastic step only (drop "
+                "precondition/cfg.precondition_k)")
+        snapshot_extra = {"precond": pre.to_extra()} if pre is not None else None
+        if (pre is not None and cfg.precondition_auto_lr
+                and cfg.schedule == "const"):
+            # The step-size rule wants the per-step J-union size: how many
+            # expansion coordinates one step scatters.
+            if execution == "mesh" and mesh is not None:
+                n_model = dict(zip(mesh.axis_names,
+                                   mesh.devices.shape)).get("model", 1)
+                j_union = n_model * cfg.n_expand
+            elif algorithm == "parallel":
+                j_union = cfg.n_workers * cfg.n_expand
+            else:
+                j_union = cfg.n_expand
+            cfg = cfg.replace(lr0=pre.step_size(j_union))
+        plan = trainer.make_plan(execution, cfg, x=x, y=y, source=source,
+                                 algorithm=algorithm, prefetch=prefetch,
+                                 eval_cache=eval_cache, mesh=mesh,
+                                 precond=pre)
+    with plan:
         return trainer.fit_loop(
             plan, key, n_epochs=n_epochs, tol=tol, x_val=x_val, y_val=y_val,
             eval_every=eval_every, verbose=verbose,
@@ -244,7 +253,6 @@ def fit(cfg: DSEKLConfig, x, y=None, key: Array = None, *,
             callback=callback, manager=manager,
             checkpoint_every=checkpoint_every, resume=resume,
             snapshot_extra=snapshot_extra, on_epoch=on_epoch)
-
 
 def error_rate(cfg: DSEKLConfig, alpha: Array, x_train: Array, x: Array,
                y: Array) -> float:

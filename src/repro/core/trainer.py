@@ -61,6 +61,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import dsekl, sampler
 from repro.core.dsekl import DSEKLConfig, DSEKLState
@@ -985,75 +986,96 @@ def fit_loop(plan: ExecutionPlan, key: Array, *, n_epochs: int = 50,
     fit after the boundary's snapshot — ``FitResult.stop_reason`` then
     reads ``"hook"``.  ``snapshot_extra`` may be a dict or a zero-arg
     callable evaluated at each snapshot (live caller state rides along
-    in the checkpoint)."""
-    state = plan.init_state()
-    history: List[Dict[str, Any]] = []
-    start = 0
-    converged = False
-    if manager is not None and resume:
-        restored = _restore(manager, plan)
-        if restored is not None:
-            state, key, start, history, converged = restored
-            if converged:
-                # The interrupted run had already met the stopping rule:
-                # an uninterrupted run would have stopped here too.
-                start = n_epochs
-            if verbose:
-                print(f"[dsekl] resumed at epoch {start} "
-                      f"({plan.name} backend)"
-                      + (" — already converged" if converged else ""))
-    sub = None
-    hook_stop = False
-    prev_alpha = np.asarray(state.alpha, np.float64)
-    if start < n_epochs:
-        key, sub = jax.random.split(key)
-        plan.plan_epoch(sub)
+    in the checkpoint).
+
+    Under ``jax.profiler.trace`` the loop writes host spans: its set-up
+    (``init_state``, resume, the first epoch's plan) is
+    ``dsekl.fit.setup``; each epoch is the step span ``dsekl.epoch``
+    (``step_num`` = epoch number) holding one span per phase:
+    ``dsekl.epoch.plan``, ``.dispatch``, ``.wait``, ``.host_delta``,
+    ``.eval``, ``.hooks`` and ``.snapshot``.  The history ``seconds`` of
+    an epoch is the host clock over its dispatch and wait."""
+    with TraceAnnotation("dsekl.fit.setup"):
+        state = plan.init_state()
+        history: List[Dict[str, Any]] = []
+        start = 0
+        converged = False
+        if manager is not None and resume:
+            restored = _restore(manager, plan)
+            if restored is not None:
+                state, key, start, history, converged = restored
+                if converged:
+                    # The interrupted run had already met the stopping
+                    # rule: an uninterrupted run would have stopped here
+                    # too.
+                    start = n_epochs
+                if verbose:
+                    print(f"[dsekl] resumed at epoch {start} "
+                          f"({plan.name} backend)"
+                          + (" — already converged" if converged else ""))
+        sub = None
+        hook_stop = False
+        prev_alpha = np.asarray(state.alpha, np.float64)
+        if start < n_epochs:
+            key, sub = jax.random.split(key)
+            plan.plan_epoch(sub)
     for e in range(start, n_epochs):
-        ckpt_key = key                          # pre-epoch carry (resume)
-        if e + 1 < n_epochs:
-            key, sub_next = jax.random.split(key)
-            plan.plan_epoch(sub_next)           # one epoch ahead
-        else:
-            sub_next = None
-        t0 = time.perf_counter()
-        state = plan.run_epoch(state, sub)
-        if truncate_every and (e + 1) % truncate_every == 0:
-            state = state._replace(
-                alpha=_truncate_smallest(state.alpha, truncate_frac))
-        state.alpha.block_until_ready()
-        dt = time.perf_counter() - t0
-        # |dalpha| on a host copy: a fixed-order float64 reduction, so the
-        # history does not depend on how alpha is sharded.
-        alpha_host = np.asarray(state.alpha, np.float64)
-        delta = float(np.sqrt(np.sum(np.square(alpha_host - prev_alpha))))
-        prev_alpha = alpha_host
-        converged = delta < tol                 # paper §4.2 stopping rule
-        rec: Dict[str, Any] = {"epoch": e + 1, "delta_alpha": delta,
-                               "seconds": dt}
-        # Evaluate on eval_every epochs AND on the last record of the fit
-        # — the final epoch or the convergence epoch (a fit stopping
-        # early off the eval cadence must not leave its last history
-        # record without a val_error).
-        if x_val is not None and (e % eval_every == 0 or converged
-                                  or e == n_epochs - 1):
-            rec["val_error"] = plan.eval_error(state, x_val, y_val)
-        history.append(rec)
-        if callback is not None:
-            callback(e, state)
-        hook_stop = bool(on_epoch(e + 1, state, rec)) \
-            if on_epoch is not None else False
-        if verbose:
-            print(f"[dsekl] epoch {e + 1}: |dalpha|={delta:.4f} "
-                  + (f"val_err={rec.get('val_error', float('nan')):.4f}"
-                     if "val_error" in rec else ""))
-        if manager is not None and (
-                (e + 1) % checkpoint_every == 0 or converged or hook_stop
-                or e == n_epochs - 1):
-            _snapshot(manager, state, ckpt_key, e + 1, history, converged,
-                      snapshot_extra, leaves=plan.snapshot_leaves(state))
-        sub = sub_next
-        if converged or hook_stop:
-            break
+        with StepTraceAnnotation("dsekl.epoch", step_num=e + 1):
+            with TraceAnnotation("dsekl.epoch.plan"):
+                ckpt_key = key                  # pre-epoch carry (resume)
+                if e + 1 < n_epochs:
+                    key, sub_next = jax.random.split(key)
+                    plan.plan_epoch(sub_next)   # one epoch ahead
+                else:
+                    sub_next = None
+            t0 = time.perf_counter()
+            with TraceAnnotation("dsekl.epoch.dispatch"):
+                state = plan.run_epoch(state, sub)
+                if truncate_every and (e + 1) % truncate_every == 0:
+                    state = state._replace(
+                        alpha=_truncate_smallest(state.alpha, truncate_frac))
+            with TraceAnnotation("dsekl.epoch.wait"):
+                state.alpha.block_until_ready()
+            dt = time.perf_counter() - t0
+            with TraceAnnotation("dsekl.epoch.host_delta"):
+                # |dalpha| on a host copy: a fixed-order float64
+                # reduction, so the history does not depend on how alpha
+                # is sharded.
+                alpha_host = np.asarray(state.alpha, np.float64)
+                delta = float(np.sqrt(np.sum(np.square(alpha_host
+                                                       - prev_alpha))))
+                prev_alpha = alpha_host
+            converged = delta < tol             # paper §4.2 stopping rule
+            rec: Dict[str, Any] = {"epoch": e + 1, "delta_alpha": delta,
+                                   "seconds": dt}
+            with TraceAnnotation("dsekl.epoch.eval"):
+                # Evaluate on eval_every epochs AND on the last record of
+                # the fit — the final epoch or the convergence epoch (a
+                # fit stopping early off the eval cadence must not leave
+                # its last history record without a val_error).
+                if x_val is not None and (e % eval_every == 0 or converged
+                                          or e == n_epochs - 1):
+                    rec["val_error"] = plan.eval_error(state, x_val, y_val)
+            history.append(rec)
+            with TraceAnnotation("dsekl.epoch.hooks"):
+                if callback is not None:
+                    callback(e, state)
+                hook_stop = bool(on_epoch(e + 1, state, rec)) \
+                    if on_epoch is not None else False
+                if verbose:
+                    print(f"[dsekl] epoch {e + 1}: |dalpha|={delta:.4f} "
+                          + (f"val_err={rec.get('val_error', float('nan')):.4f}"
+                             if "val_error" in rec else ""))
+            with TraceAnnotation("dsekl.epoch.snapshot"):
+                if manager is not None and (
+                        (e + 1) % checkpoint_every == 0 or converged
+                        or hook_stop or e == n_epochs - 1):
+                    _snapshot(manager, state, ckpt_key, e + 1, history,
+                              converged, snapshot_extra,
+                              leaves=plan.snapshot_leaves(state))
+            sub = sub_next
+            if converged or hook_stop:
+                break
     if manager is not None:
         manager.wait()
     return FitResult(state=state, history=history, converged=converged,

@@ -121,3 +121,30 @@ def test_gradient_step_core_compiles(one_chip, d):
     _compile(lambda xi, yi, xj, aj: dsekl.grad_block(cfg, xi, yi, xj, aj),
              _f32(one_chip, 1024, d), _f32(one_chip, 1024),
              _f32(one_chip, 1024, d), _f32(one_chip, 1024))
+
+
+def test_covertype_epoch_keeps_the_kernel_name_and_the_step_scopes(one_chip):
+    """The epoch scan the benchmark's training cell runs (covertype,
+    572,820 x 54): the fused train pass keeps the HLO name
+    ``kernel_dual_pass`` that a trace reader matches (the step's named
+    scopes are metadata only), and the step's ops carry the scopes."""
+    import re
+
+    from repro.core import trainer
+
+    n, d = 572820, 54
+    cfg = dsekl.DSEKLConfig(n_grad=1024, n_expand=1024, lam=1.0 / n,
+                            impl="pallas")
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: dsekl.init_state(n)))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = trainer._epoch_serial.lower(
+        cfg, state, _f32(one_chip, n, d), _f32(one_chip, n), key
+    ).compile().as_text()
+    kernel = [ln for ln in text.splitlines() if re.search(
+        r"^%kernel_dual_pass[.\d]* = .*tpu_custom_call", ln.strip())]
+    assert len(kernel) == 1, kernel
+    assert "/dsekl.train_pass/" in kernel[0]
+    for scope in ("sample", "gather", "train_pass", "update"):
+        assert f"/dsekl.{scope}/" in text, scope
