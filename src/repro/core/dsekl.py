@@ -456,12 +456,56 @@ grad_block_parallel_precond_jit = jax.jit(grad_block_parallel_precond,
 
 
 # ---------------------------------------------------------------------------
+# The matrix the in-memory steps gather rows from.
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+@jax.tree_util.register_pytree_node_class
+class PaddedRows:
+    """X (N, D) held as ``rows`` (N, 128 * ceil(D / 128)): zero columns up
+    to a whole number of lanes.  A TPU lays an f32 (N, D) matrix with D
+    not a multiple of 128 out column-major (rows on the lanes), so each
+    gathered row spans ceil(D / 8) tiles; the padded matrix is row-major
+    and a row is one contiguous tile row.  Indexing gathers padded rows
+    and drops the padding, so ``x[idx]`` is bit-for-bit the unpadded
+    gather and the train pass sees (|I|, D) as before.  ``d`` is static
+    (pytree aux data): the steps take either this or a plain array."""
+
+    def __init__(self, rows: Array, d: int):
+        self.rows, self.d = rows, int(d)
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return (self.rows.shape[0], self.d)
+
+    def __getitem__(self, idx: Array) -> Array:
+        return self.rows[idx][..., :self.d]
+
+    def tree_flatten(self):
+        return (self.rows,), self.d
+
+    @classmethod
+    def tree_unflatten(cls, d, children):
+        return cls(children[0], d)
+
+
+@jax.jit
+def pad_lanes(x: Array) -> PaddedRows:
+    """The lane-padded copy of x (N, D), as ``PaddedRows``."""
+    d = x.shape[1]
+    return PaddedRows(jnp.pad(x, ((0, 0), (0, -(-d // LANES) * LANES - d))),
+                      d)
+
+
+# ---------------------------------------------------------------------------
 # Algorithm 1 — serial doubly stochastic kernel learning.
 # ---------------------------------------------------------------------------
 
 def step_serial(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
                 key: Array, pc: PrecondBlock = None) -> DSEKLState:
-    """One Alg.-1 iteration.  x (N, D), y (N,).
+    """One Alg.-1 iteration.  x (N, D) or its ``PaddedRows``, y (N,).
 
     Thin in-memory wrapper over the block-parametrized core: gather the
     sampled blocks on device, compute the block gradient, scatter.  With
@@ -500,8 +544,9 @@ def _parallel_inner(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
                     pc: PrecondBlock = None) -> DSEKLState:
     """Process ONE gradient batch against K expansion batches (Alg. 2 body).
 
-    idx_i (i_batch,);  idx_jk (K, j_batch) — disjoint worker batches.
-    Thin in-memory wrapper over the block-parametrized core.
+    idx_i (i_batch,);  idx_jk (K, j_batch) — disjoint worker batches;
+    x (N, D) or its ``PaddedRows``.  Thin in-memory wrapper over the
+    block-parametrized core.
     """
     n = x.shape[0]
     with jax.named_scope("dsekl.gather"):

@@ -94,6 +94,10 @@ class FitResult:
     # comparable head-to-head without reaching into ``history``.
     epochs_to_tol: Optional[int] = None
     final_residual: float = 0.0
+    # Width of the rows an in-memory fit's epochs gather: D when X was
+    # used as given, 128 * ceil(D / 128) when the plan gathered from a
+    # lane-padded copy (``_gather_matrix``).  None for the other plans.
+    row_width: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -183,6 +187,32 @@ def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
+def _gather_matrix(x: Array):
+    """The matrix an in-memory plan's steps gather rows from: x as given,
+    or its lane-padded copy (``dsekl.PaddedRows``) where a row gather from
+    x would be strided, namely where x's layout puts the rows on the lanes
+    (features major; where the layout cannot be read, D not a multiple of
+    128 on a TPU), x sits on one device, and the copy fits in half of that
+    device's free memory.  A CPU lays x out row-major: x as given."""
+    if not isinstance(x, jax.Array) or len(x.devices()) != 1:
+        return x
+    n, d = x.shape
+    width = _round_up(d, dsekl.LANES)
+    if width == d:
+        return x
+    (device,) = x.devices()
+    layout = x.format.layout                # None where it cannot be read
+    strided = (layout.major_to_minor == (1, 0) if layout is not None
+               else device.platform == "tpu")
+    if not strided:
+        return x
+    stats = device.memory_stats() or {}
+    free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+    if 2 * n * width * x.dtype.itemsize > free:
+        return x
+    return dsekl.pad_lanes(x)
+
+
 def _make_val_engine(cfg: DSEKLConfig, x: Array, n_val: int):
     """Keep-all prediction engine for the validation eval path.
 
@@ -251,6 +281,9 @@ class ExecutionPlan:
         raise NotImplementedError
 
     # -- eval / reporting -----------------------------------------------
+    # ``FitResult.row_width`` (set by the in-memory plans).
+    row_width: Optional[int] = None
+
     def eval_error(self, state: DSEKLState, x_val: Array,
                    y_val: Array) -> float:
         raise NotImplementedError
@@ -273,7 +306,11 @@ class ExecutionPlan:
 
 class _InMemoryPlan(ExecutionPlan):
     """Shared base of the device-resident backends: data on device,
-    eval through the cached prediction engine or the jitted error."""
+    eval through the cached prediction engine or the jitted error.
+
+    The epochs gather rows from ``_gather_matrix(x)``, made once per plan
+    (in ``init_state``, so inside ``fit``'s set-up span) and dropped on
+    ``close``; eval and the caller keep the unpadded ``x``."""
 
     def __init__(self, cfg: DSEKLConfig, x: Array, y: Array, *,
                  eval_cache: bool = False,
@@ -283,6 +320,22 @@ class _InMemoryPlan(ExecutionPlan):
         self.precond = precond
         self._eval_cache = bool(eval_cache)
         self._val_engine = None
+        self._x_rows = None
+
+    def _rows(self):
+        if self._x_rows is None:
+            self._x_rows = _gather_matrix(self.x)
+            self.row_width = (self._x_rows.rows.shape[1]
+                              if isinstance(self._x_rows, dsekl.PaddedRows)
+                              else int(self.x.shape[1]))
+        return self._x_rows
+
+    def init_state(self) -> DSEKLState:
+        self._rows()
+        return super().init_state()
+
+    def close(self) -> None:
+        self._x_rows = None
 
     def eval_error(self, state: DSEKLState, x_val: Array,
                    y_val: Array) -> float:
@@ -307,7 +360,7 @@ class SerialPlan(_InMemoryPlan):
     name = "serial"
 
     def run_epoch(self, state: DSEKLState, key: Array) -> DSEKLState:
-        return _epoch_serial(self.cfg, state, self.x, self.y, key,
+        return _epoch_serial(self.cfg, state, self._rows(), self.y, key,
                              self.precond)
 
 
@@ -317,7 +370,7 @@ class ParallelPlan(_InMemoryPlan):
     name = "parallel"
 
     def run_epoch(self, state: DSEKLState, key: Array) -> DSEKLState:
-        return _epoch_parallel(self.cfg, state, self.x, self.y, key,
+        return _epoch_parallel(self.cfg, state, self._rows(), self.y, key,
                                self.precond)
 
 
@@ -1090,7 +1143,8 @@ def fit_loop(plan: ExecutionPlan, key: Array, *, n_epochs: int = 50,
                          (h["epoch"] for h in history
                           if h["delta_alpha"] < tol), None),
                      final_residual=(history[-1]["delta_alpha"]
-                                     if history else 0.0))
+                                     if history else 0.0),
+                     row_width=plan.row_width)
 
 
 def resolve_execution(execution: Optional[str], cfg: DSEKLConfig, *,

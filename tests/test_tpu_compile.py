@@ -148,3 +148,36 @@ def test_covertype_epoch_keeps_the_kernel_name_and_the_step_scopes(one_chip):
     assert "/dsekl.train_pass/" in kernel[0]
     for scope in ("sample", "gather", "train_pass", "update"):
         assert f"/dsekl.{scope}/" in text, scope
+
+
+def test_covertype_epoch_gathers_from_row_major_padded_rows(one_chip):
+    """The same epoch through the lane-padded rows an in-memory plan makes
+    on a TPU: X enters row-major (an f32 (N, 54) matrix would enter
+    column-major, a row gather then reading 7 tiles a row), no gather
+    reads the column-major matrix, and the fused train pass keeps its HLO
+    name and its (1024, 54) operands."""
+    import re
+
+    from repro.core import trainer
+
+    n, d = 572820, 54
+    cfg = dsekl.DSEKLConfig(n_grad=1024, n_expand=1024, lam=1.0 / n,
+                            impl="pallas")
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: dsekl.init_state(n)))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    rows = dsekl.PaddedRows(_f32(one_chip, n, 128), d)
+    text = trainer._epoch_serial.lower(
+        cfg, state, rows, _f32(one_chip, n), key).compile().as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    assert "f32[572820,128]{1,0" in entry.group(1), entry.group(1)
+    assert "f32[572820,54]{0,1" not in text
+    gathers = [ln for ln in text.splitlines()
+               if re.search(r"= f32\[1024,128\]\{1,0.* gather\(", ln)]
+    assert len(gathers) == 2, gathers
+    kernel = [ln for ln in text.splitlines() if re.search(
+        r"^%kernel_dual_pass[.\d]* = .*tpu_custom_call", ln.strip())]
+    assert len(kernel) == 1, kernel
+    assert ("operand_layout_constraints={f32[1024,54]{1,0}, "
+            "f32[1024,54]{1,0}," in kernel[0]), kernel[0]

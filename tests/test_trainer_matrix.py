@@ -23,10 +23,11 @@ import textwrap
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DSEKLConfig, fit, trainer
+from repro.core import DSEKLConfig, dsekl, fit, trainer
 from repro.data import HostSource, make_xor
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -73,6 +74,38 @@ def test_matrix_inmemory_hosted_bitidentical(xy, src, algorithm):
     r_cfg = fit(cfg.replace(execution=algorithm), x, y, key, n_epochs=3,
                 tol=0.0)
     _assert_states_identical(r_mem.state, r_cfg.state)
+
+
+@pytest.mark.parametrize("algorithm,kernel,precondition,d", [
+    ("serial", "rbf", 0, 54),
+    ("serial", "laplacian", 0, 54),
+    ("serial", "rbf", 4, 54),
+    ("serial", "rbf", 0, 130),
+    ("parallel", "rbf", 0, 54),
+    ("parallel", "laplacian", 0, 54),
+])
+def test_lane_padded_rows_bitidentical(monkeypatch, algorithm, kernel,
+                                       precondition, d):
+    """Gathering from the lane-padded copy of X (what an in-memory plan
+    does on a TPU, where X of D % 128 != 0 sits column-major) gives the
+    alpha of the unpadded gather bit for bit.  A CPU lays X out row-major,
+    so a plain fit uses X as given; the padding is forced here."""
+    kx, ky = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(kx, (240, d))
+    y = jnp.sign(jax.random.normal(ky, (240,)))
+    cfg = DSEKLConfig(n_grad=24, n_expand=16, lam=1e-4, schedule="adagrad",
+                      n_workers=3 if algorithm == "parallel" else 1,
+                      kernel=kernel, kernel_params=(("gamma", 0.1),),
+                      precondition_k=precondition, impl="ref")
+    key = jax.random.PRNGKey(7)
+    kw = dict(execution=algorithm, n_epochs=3, tol=0.0)
+    r_given = fit(cfg, x, y, key, **kw)
+    assert r_given.row_width == d
+    monkeypatch.setattr(trainer, "_gather_matrix", dsekl.pad_lanes)
+    r_padded = fit(cfg, x, y, key, **kw)
+    assert r_padded.row_width == -(-d // 128) * 128
+    assert np.any(np.asarray(r_padded.state.alpha) != 0)
+    _assert_states_identical(r_given.state, r_padded.state)
 
 
 def test_execution_resolution_and_errors(xy, src):
