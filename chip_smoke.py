@@ -320,7 +320,7 @@ def phase_mesh(x, y, xv, yv, seed: int):
     _, sub = jax.random.split(key)
     t0 = time.perf_counter()
     for k in jax.random.split(sub, steps):
-        alpha, accum, t = sim(cfg, 4, 1, x, y, alpha, accum, t, k)
+        alpha, accum, t = sim(cfg, 4, 1, x, y, alpha, accum, t, k, epoch=1)
     alpha = jax.block_until_ready(alpha)
     t_sim = time.perf_counter() - t0
     err_m = res.history[-1]["val_error"]

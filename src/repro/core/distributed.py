@@ -18,7 +18,9 @@ N and D:
 This is the low-communication distributed variant the paper's conclusion
 calls for.  Semantics match Algorithm 2 (jointly-evaluated kernel map +
 AdaGrad dampening); ``simulate_step`` reproduces the math on one device so
-tests can assert exact agreement.
+tests can assert exact agreement.  The rate is the serial plan's: the
+state carries the epoch the step runs in, so ``inv_epoch`` steps at
+lr0 / epoch and ``inv_t`` at lr0 / t.
 
 With ``cfg.stream_row_block > 0`` the fused ref-path step streams: K_{I,J}
 is consumed in (row_block, |J|) tiles with the model-axis psum completed per
@@ -48,6 +50,10 @@ class ShardedDSEKLState(NamedTuple):
     alpha: Array    # (N,) sharded over 'model'
     accum: Array    # (N,) sharded over 'model'
     step: Array     # () replicated
+    # () replicated: the epoch the step runs in, which ``inv_epoch``'s rate
+    # reads (as ``DSEKLState.epoch`` does for the serial plan).  The step
+    # passes it through; the driver of the epochs sets it (``MeshPlan``).
+    epoch: Array
 
 
 def _shard_block_grad_v(cfg: DSEKLConfig, n_global: int, xi: Array,
@@ -96,18 +102,24 @@ def _shard_block_grad_v(cfg: DSEKLConfig, n_global: int, xi: Array,
         v = loss.grad_f(f, yi)
         g = kb.T @ v
     else:
-        f = jax.lax.psum(dsekl._block_f(cfg, xi, xj, aj, n_global), model_axis)
-        if cfg.unbiased_scaling:
-            f = f / jax.lax.psum(1, model_axis)
-        v = loss.grad_f(f, yi)
-        # Data-dependent part only; aggregate over every data shard's
-        # I-batch, then add the regularizer ONCE (not once per data shard).
-        g = dsekl._block_grad(cfg.replace(lam=0.0), xi, xj, aj, v)
-    if cfg.compress_bits:
-        g = compression.compressed_psum(
-            g, data_axis, jax.random.fold_in(key, 2), bits=cfg.compress_bits)
-    else:
-        g = jax.lax.psum(g, data_axis)
+        with jax.named_scope("dsekl.mesh.f_pass"):
+            f = jax.lax.psum(dsekl._block_f(cfg, xi, xj, aj, n_global),
+                             model_axis)
+            if cfg.unbiased_scaling:
+                f = f / jax.lax.psum(1, model_axis)
+            v = loss.grad_f(f, yi)
+        with jax.named_scope("dsekl.mesh.g_pass"):
+            # Data-dependent part only; aggregate over every data shard's
+            # I-batch, then add the regularizer ONCE (not once per data
+            # shard).
+            g = dsekl._block_grad(cfg.replace(lam=0.0), xi, xj, aj, v)
+    with jax.named_scope("dsekl.mesh.psum"):
+        if cfg.compress_bits:
+            g = compression.compressed_psum(
+                g, data_axis, jax.random.fold_in(key, 2),
+                bits=cfg.compress_bits)
+        else:
+            g = jax.lax.psum(g, data_axis)
     return g + cfg.lam * aj, v
 
 
@@ -126,9 +138,13 @@ def _shard_block_grad(cfg: DSEKLConfig, n_global: int, xi: Array, yi: Array,
 
 
 def _apply_shard_update(cfg: DSEKLConfig, alpha: Array, accum: Array,
-                        step: Array, idx_j: Array, g: Array
+                        step: Array, epoch: Array, idx_j: Array, g: Array
                         ) -> Tuple[Array, Array, Array]:
     """Scatter one shard gradient into the local alpha/accum shard.
+
+    The rate is ``dsekl._lr`` of the step count t and the ``epoch`` the
+    step runs in, as in the serial plan: lr0 / t under ``inv_t``,
+    lr0 / epoch under ``inv_epoch``.
 
     Like the single-device ``apply_update``/``apply_update_parallel``,
     the AdaGrad accumulator is touched ONLY under ``schedule="adagrad"``
@@ -136,7 +152,7 @@ def _apply_shard_update(cfg: DSEKLConfig, alpha: Array, accum: Array,
     step and checkpoint a silently mutated accumulator (alpha was
     unaffected: the damp factor was ones)."""
     t = step + 1
-    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, t))
+    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, epoch))
     if cfg.schedule == "adagrad":
         accum = accum.at[idx_j].add(g * g)
         damp = jax.lax.rsqrt(accum[idx_j])
@@ -148,8 +164,8 @@ def _apply_shard_update(cfg: DSEKLConfig, alpha: Array, accum: Array,
 
 def _local_step(cfg: DSEKLConfig, n_global: int,
                 x_grad: Array, y_grad: Array, x_exp: Array,
-                alpha: Array, accum: Array, step: Array, key: Array,
-                *, data_axis: str, model_axis: str
+                alpha: Array, accum: Array, step: Array, epoch: Array,
+                key: Array, *, data_axis: str, model_axis: str
                 ) -> Tuple[Array, Array, Array]:
     """Per-device body (runs under shard_map): sample, gather, block step."""
     d_id = jax.lax.axis_index(data_axis)
@@ -166,13 +182,14 @@ def _local_step(cfg: DSEKLConfig, n_global: int,
 
     g = _shard_block_grad(cfg, n_global, xi, yi, xj, aj, key,
                           data_axis=data_axis, model_axis=model_axis)
-    return _apply_shard_update(cfg, alpha, accum, step, idx_j, g)
+    with jax.named_scope("dsekl.mesh.update"):
+        return _apply_shard_update(cfg, alpha, accum, step, epoch, idx_j, g)
 
 
 def _local_block_step(cfg: DSEKLConfig, n_global: int,
                       xi: Array, yi: Array, xj: Array, idx_j: Array,
-                      alpha: Array, accum: Array, step: Array, key: Array,
-                      *, data_axis: str, model_axis: str
+                      alpha: Array, accum: Array, step: Array, epoch: Array,
+                      key: Array, *, data_axis: str, model_axis: str
                       ) -> Tuple[Array, Array, Array]:
     """Per-device body for PRE-GATHERED blocks (the out-of-core mesh step):
     the data plane supplies this shard's sampled gradient rows (xi, yi),
@@ -181,14 +198,15 @@ def _local_block_step(cfg: DSEKLConfig, n_global: int,
     aj = alpha[idx_j]
     g = _shard_block_grad(cfg, n_global, xi, yi, xj, aj, key,
                           data_axis=data_axis, model_axis=model_axis)
-    return _apply_shard_update(cfg, alpha, accum, step, idx_j, g)
+    with jax.named_scope("dsekl.mesh.update"):
+        return _apply_shard_update(cfg, alpha, accum, step, epoch, idx_j, g)
 
 
 def _local_block_step_precond(cfg: DSEKLConfig, n_global: int,
                               xi: Array, yi: Array, xj: Array, idx_j: Array,
                               alpha: Array, accum: Array, step: Array,
-                              key: Array, p_rows: Array, p_vecs: Array,
-                              p_damp: Array, p_idx: Array,
+                              epoch: Array, key: Array, p_rows: Array,
+                              p_vecs: Array, p_damp: Array, p_idx: Array,
                               *, data_axis: str, model_axis: str
                               ) -> Tuple[Array, Array, Array]:
     """``_local_block_step`` plus the EigenPro correction (DESIGN.md §10).
@@ -216,11 +234,12 @@ def _local_block_step_precond(cfg: DSEKLConfig, n_global: int,
     # n_expand block (axis size is static, so this folds to a constant).
     j_union = xj.shape[0] * jax.lax.psum(1, model_axis)
     delta = p_vecs @ ((j_union * p_damp) * (p_vecs.T @ c))
-    alpha, accum, t = _apply_shard_update(cfg, alpha, accum, step, idx_j, g)
+    alpha, accum, t = _apply_shard_update(cfg, alpha, accum, step, epoch,
+                                          idx_j, g)
     rows_m = alpha.shape[0]
     local = p_idx - jax.lax.axis_index(model_axis) * rows_m
     safe = jnp.where((local >= 0) & (local < rows_m), local, rows_m)
-    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, t))
+    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, epoch))
     alpha = alpha.at[safe].add(lr * delta)      # OOB updates are dropped
     return alpha, accum, t
 
@@ -238,7 +257,7 @@ def make_distributed_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
-                  P(model_axis), P(model_axis), P(), P()),
+                  P(model_axis), P(model_axis), P(), P(), P()),
         out_specs=(P(model_axis), P(model_axis), P()),
         check_vma=False,
     )
@@ -246,12 +265,13 @@ def make_distributed_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     @jax.jit
     def step(x_grad, y_grad, x_exp, state: ShardedDSEKLState, key):
         alpha, accum, t = mapped(x_grad, y_grad, x_exp, state.alpha,
-                                 state.accum, state.step, key)
-        return ShardedDSEKLState(alpha, accum, t)
+                                 state.accum, state.step, state.epoch, key)
+        return ShardedDSEKLState(alpha, accum, t, state.epoch)
 
     return step
 
 
+@functools.lru_cache(maxsize=8)
 def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
                                 data_axis: str = "data",
                                 model_axis: str = "model",
@@ -277,6 +297,10 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     With ``precondition=True`` the returned step takes a trailing
     ``dsekl.PrecondBlock`` (replicated; GLOBAL indices) and applies the
     EigenPro correction — one extra (m,)-float data-axis psum per step.
+
+    Built once per argument set: every ``MeshPlan`` (one per fit) on the
+    same mesh, config and N gets the same jitted step, so a fit after the
+    first traces and compiles nothing.
     """
     xi_sh = NamedSharding(mesh, P(data_axis, None))
     yi_sh = NamedSharding(mesh, P(data_axis))
@@ -302,7 +326,7 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
             body, mesh=mesh,
             in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
                       P(model_axis), P(model_axis), P(model_axis), P(), P(),
-                      P(), P(), P(), P()),
+                      P(), P(), P(), P(), P()),
             out_specs=(P(model_axis), P(model_axis), P()),
             check_vma=False,
         )
@@ -311,10 +335,10 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
         def step(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
                  pc: dsekl.PrecondBlock):
             alpha, accum, t = mapped(xi, yi, xj, idx_j, state.alpha,
-                                     state.accum, state.step, key,
-                                     pc.rows, pc.vectors, pc.damping,
+                                     state.accum, state.step, state.epoch,
+                                     key, pc.rows, pc.vectors, pc.damping,
                                      pc.indices)
-            return ShardedDSEKLState(alpha, accum, t)
+            return ShardedDSEKLState(alpha, accum, t, state.epoch)
 
         def step_host(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
                       pc: dsekl.PrecondBlock):
@@ -331,7 +355,7 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
-                  P(model_axis), P(model_axis), P(model_axis), P(), P()),
+                  P(model_axis), P(model_axis), P(model_axis), P(), P(), P()),
         out_specs=(P(model_axis), P(model_axis), P()),
         check_vma=False,
     )
@@ -339,8 +363,8 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
     @jax.jit
     def step(xi, yi, xj, idx_j, state: ShardedDSEKLState, key):
         alpha, accum, t = mapped(xi, yi, xj, idx_j, state.alpha,
-                                 state.accum, state.step, key)
-        return ShardedDSEKLState(alpha, accum, t)
+                                 state.accum, state.step, state.epoch, key)
+        return ShardedDSEKLState(alpha, accum, t, state.epoch)
 
     def step_host(xi, yi, xj, idx_j, state: ShardedDSEKLState, key):
         """Host-array front door: device_put the gathered blocks straight
@@ -464,6 +488,7 @@ def init_sharded_state(mesh: Mesh, n: int, model_axis: str = "model"
         alpha=jax.device_put(jnp.zeros((n,), jnp.float32), sh),
         accum=jax.device_put(jnp.ones((n,), jnp.float32), sh),
         step=jnp.zeros((), jnp.int32),
+        epoch=jnp.zeros((), jnp.int32),
     )
 
 
@@ -474,12 +499,14 @@ def init_sharded_state(mesh: Mesh, n: int, model_axis: str = "model"
 def simulate_step(cfg: DSEKLConfig, n_data_shards: int, n_model_shards: int,
                   x: Array, y: Array, alpha: Array, accum: Array,
                   step: Array, key: Array,
-                  pc=None) -> Tuple[Array, Array, Array]:
+                  pc=None, epoch=0) -> Tuple[Array, Array, Array]:
     """Exactly reproduce the mesh step's math on one device (loops over
     shards).  Used by tests to validate the shard_map implementation.
     ``pc`` (a ``dsekl.PrecondBlock``) reproduces the preconditioned step:
     the per-model-shard out-of-bounds-dropped scatters of the replicated
-    correction compose to ONE global scatter at ``pc.indices``."""
+    correction compose to ONE global scatter at ``pc.indices``.
+    ``epoch`` is the epoch the step runs in (``ShardedDSEKLState.epoch``):
+    ``inv_epoch`` steps at lr0 / epoch; 0, a fresh state's, reads lr0."""
     n = x.shape[0]
     loss = losses_lib.get_loss(cfg.loss)
     rows_d = n // n_data_shards
@@ -508,7 +535,8 @@ def simulate_step(cfg: DSEKLConfig, n_data_shards: int, n_model_shards: int,
 
     t = step + 1
     new_alpha, new_accum = alpha, accum
-    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, t))
+    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t,
+                                         jnp.asarray(epoch, jnp.int32)))
     for m in range(n_model_shards):
         aj = alpha[idx_j[m]]
         g = jnp.zeros((cfg.n_expand,), jnp.float32)

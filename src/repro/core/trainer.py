@@ -637,23 +637,31 @@ class MeshPlan(ExecutionPlan):
         return self._queued.popleft()
 
     def run_epoch(self, state: DSEKLState, key: Array) -> DSEKLState:
+        """One epoch of mesh steps.  The steps run in epoch
+        ``state.epoch + 1``, the serial plan's numbering, which the
+        ``inv_epoch`` rate reads.  Under ``jax.profiler.trace`` each step
+        writes ``dsekl.mesh.wait`` (the wait for the prefetcher's blocks)
+        and ``dsekl.mesh.step`` (the step's dispatch)."""
         from repro.core import distributed as dist
 
         _, step_keys = self._pop_plan(key)
-        sh = dist.ShardedDSEKLState(state.alpha, state.accum, state.step)
+        sh = dist.ShardedDSEKLState(state.alpha, state.accum, state.step,
+                                    state.epoch + 1)
         pc = self.precond
         loader = self._loader
         for t in range(self.steps_per_epoch):
-            xi, yi, xj, idx_j = loader.get()
-            k = jnp.asarray(step_keys[t])
-            if pc is None:
-                sh = self.step_host(xi, yi, xj, idx_j, sh, k)
-            else:
-                sh = self.step_host(xi, yi, xj, idx_j, sh, k, pc)
+            with TraceAnnotation("dsekl.mesh.wait"):
+                xi, yi, xj, idx_j = loader.get()
+            with TraceAnnotation("dsekl.mesh.step"):
+                k = jnp.asarray(step_keys[t])
+                if pc is None:
+                    sh = self.step_host(xi, yi, xj, idx_j, sh, k)
+                else:
+                    sh = self.step_host(xi, yi, xj, idx_j, sh, k, pc)
         sh.alpha.block_until_ready()            # epoch-boundary sync
         self._consumed_steps += self.steps_per_epoch
         return DSEKLState(alpha=sh.alpha, accum=sh.accum, step=sh.step,
-                          epoch=state.epoch + 1)
+                          epoch=sh.epoch)
 
     def eval_error(self, state: DSEKLState, x_val: Array,
                    y_val: Array) -> float:

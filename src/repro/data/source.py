@@ -493,8 +493,14 @@ class BlockPrefetcher:
 
     ``stats()`` reports how much of the gather work the overlap hid:
     ``gather_s`` is worker time spent copying/transferring rows,
-    ``wait_s`` is consumer time blocked on an unfilled buffer.
+    ``wait_s`` is consumer time blocked on an unfilled buffer, and
+    ``not_ready`` counts the steps whose blocks were not ready when the
+    consumer asked for them.  Under ``jax.profiler.trace`` the worker
+    writes a ``GATHER_SPAN`` and an ``H2D_SPAN`` host span per step.
     """
+
+    GATHER_SPAN = "dsekl.loader.gather"
+    H2D_SPAN = "dsekl.loader.h2d"
 
     def __init__(self, source: DataSource,
                  plan_i: Optional[np.ndarray] = None,
@@ -524,6 +530,7 @@ class BlockPrefetcher:
         self._stop = False
         self.gather_s = 0.0
         self.wait_s = 0.0
+        self.not_ready = 0
         if plan_i is not None:
             self.extend(plan_i, plan_j)
         self._thread = threading.Thread(target=self._worker, daemon=True)
@@ -603,6 +610,7 @@ class BlockPrefetcher:
     def _worker(self) -> None:
         try:
             import jax
+            from jax.profiler import TraceAnnotation
             for idx_i, idx_j in self._next_indices():
                 bufs = None
                 if self._staging:
@@ -615,20 +623,25 @@ class BlockPrefetcher:
                             continue
                 t0 = time.perf_counter()
                 if self._staging:
-                    host = self._gather_staged(idx_i, idx_j, bufs)
+                    with TraceAnnotation(self.GATHER_SPAN):
+                        host = self._gather_staged(idx_i, idx_j, bufs)
                     if self._to_device:
-                        item = self._transfer(host)
-                        # Wait for the DMA (worker-side only) so the
-                        # staging buffer is reusable the moment it
-                        # re-enters the free queue; the consumer never
-                        # blocks on a transfer.
-                        jax.block_until_ready(item)
+                        with TraceAnnotation(self.H2D_SPAN):
+                            item = self._transfer(host)
+                            # Wait for the DMA (worker-side only) so the
+                            # staging buffer is reusable the moment it
+                            # re-enters the free queue; the consumer never
+                            # blocks on a transfer.
+                            jax.block_until_ready(item)
                         self._free.put(bufs)
                     else:
                         item = bufs
                 else:
-                    item = self._transfer(self._gather_fresh(idx_i, idx_j))
-                    jax.block_until_ready(item)
+                    with TraceAnnotation(self.GATHER_SPAN):
+                        host = self._gather_fresh(idx_i, idx_j)
+                    with TraceAnnotation(self.H2D_SPAN):
+                        item = self._transfer(host)
+                        jax.block_until_ready(item)
                 self.gather_s += time.perf_counter() - t0
                 while True:
                     if self._stop:
@@ -654,7 +667,11 @@ class BlockPrefetcher:
             self._free.put(self._inflight)
             self._inflight = None
         t0 = time.perf_counter()
-        item = self._ready.get()
+        try:
+            item = self._ready.get_nowait()
+        except queue.Empty:
+            self.not_ready += 1
+            item = self._ready.get()
         self.wait_s += time.perf_counter() - t0
         if isinstance(item, Exception):
             raise item
@@ -675,7 +692,7 @@ class BlockPrefetcher:
 
     def stats(self) -> dict:
         return {"steps": self.steps, "gather_s": self.gather_s,
-                "wait_s": self.wait_s}
+                "wait_s": self.wait_s, "not_ready": self.not_ready}
 
 
 class SyncGather:
@@ -696,6 +713,7 @@ class SyncGather:
         self.steps = 0
         self._to_device = to_device
         self.gather_s = 0.0
+        self.not_ready = 0
         if plan_i is not None:
             self.extend(plan_i, plan_j)
 
@@ -710,6 +728,7 @@ class SyncGather:
     def get(self) -> Tuple:
         t0 = time.perf_counter()
         idx_i, idx_j = self._steps.popleft()
+        self.not_ready += 1
         xi, yi = self._source.gather(idx_i)
         xj = self._source.gather_x(idx_j.reshape(-1))
         if self._to_device:
@@ -728,8 +747,10 @@ class SyncGather:
         pass
 
     def stats(self) -> dict:
+        # Inline: the consumer waits out every gather, and no block is
+        # ever ready before it is asked for.
         return {"steps": self.steps, "gather_s": self.gather_s,
-                "wait_s": self.gather_s}
+                "wait_s": self.gather_s, "not_ready": self.not_ready}
 
 
 # ---------------------------------------------------------------------------
@@ -778,6 +799,9 @@ class MeshPrefetcher(BlockPrefetcher):
     an elastic rescale must re-split the sources and build a fresh
     prefetcher (which resume does — the loader never outlives the plan).
     """
+
+    GATHER_SPAN = "dsekl.mesh.gather"
+    H2D_SPAN = "dsekl.mesh.h2d"
 
     def __init__(self, data_sources: List[DataSource],
                  model_sources: List[DataSource], shardings: Tuple,
@@ -858,6 +882,7 @@ class SyncMeshGather:
             collections.deque()
         self.steps = 0
         self.gather_s = 0.0
+        self.not_ready = 0
         self._n_shards: Optional[Tuple[int, int]] = None
         if plan_i is not None:
             self.extend(plan_i, plan_j)
@@ -885,6 +910,7 @@ class SyncMeshGather:
     def get(self) -> Tuple:
         t0 = time.perf_counter()
         idx_i, idx_j = self._steps.popleft()
+        self.not_ready += 1
         gi = [s.gather(idx_i[d]) for d, s in enumerate(self._data_sources)]
         xi = np.concatenate([g[0] for g in gi])
         yi = np.concatenate([g[1] for g in gi])
@@ -903,8 +929,10 @@ class SyncMeshGather:
         pass
 
     def stats(self) -> dict:
+        # Inline: the consumer waits out every gather, and no block is
+        # ever ready before it is asked for.
         return {"steps": self.steps, "gather_s": self.gather_s,
-                "wait_s": self.gather_s}
+                "wait_s": self.gather_s, "not_ready": self.not_ready}
 
 
 # ---------------------------------------------------------------------------

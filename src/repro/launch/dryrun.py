@@ -301,7 +301,8 @@ def build_dsekl_cell(shape_name: str, multi_pod: bool):
     state = dsekl_dist.ShardedDSEKLState(
         alpha=jax.ShapeDtypeStruct((n,), jnp.float32),
         accum=jax.ShapeDtypeStruct((n,), jnp.float32),
-        step=jax.ShapeDtypeStruct((), jnp.int32))
+        step=jax.ShapeDtypeStruct((), jnp.int32),
+        epoch=jax.ShapeDtypeStruct((), jnp.int32))
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     dpspec = P(data_axes)
     in_sh = (NamedSharding(mesh, P(data_axes, None)),
@@ -310,12 +311,14 @@ def build_dsekl_cell(shape_name: str, multi_pod: bool):
              dsekl_dist.ShardedDSEKLState(
                  alpha=NamedSharding(mesh, P("model")),
                  accum=NamedSharding(mesh, P("model")),
-                 step=NamedSharding(mesh, P())),
+                 step=NamedSharding(mesh, P()),
+                 epoch=NamedSharding(mesh, P())),
              NamedSharding(mesh, P()))
     out_sh = dsekl_dist.ShardedDSEKLState(
         alpha=NamedSharding(mesh, P("model")),
         accum=NamedSharding(mesh, P("model")),
-        step=NamedSharding(mesh, P()))
+        step=NamedSharding(mesh, P()),
+        epoch=NamedSharding(mesh, P()))
     n_chips = 512 if multi_pod else 256
     meta = {"arch": "dsekl", "shape": shape_name,
             "mesh": "2x16x16" if multi_pod else "16x16",

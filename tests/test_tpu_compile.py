@@ -29,12 +29,12 @@ N_QUERY, N_SV = 1024, 65536        # one serving query block x support set
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
         from jax.experimental import topologies
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            desc = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:                  # no TPU compiler here
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -43,9 +43,14 @@ def one_chip():
         enabled = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
-        yield SingleDeviceSharding(topo.devices[0])
+        yield desc
         jax.config.update("jax_enable_compilation_cache", enabled)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes):
@@ -181,3 +186,52 @@ def test_covertype_epoch_gathers_from_row_major_padded_rows(one_chip):
     assert len(kernel) == 1, kernel
     assert ("operand_layout_constraints={f32[1024,54]{1,0}, "
             "f32[1024,54]{1,0}," in kernel[0]), kernel[0]
+
+
+def test_higgs_mesh_step_holds_one_gradient_all_reduce(topo):
+    """The mesh block step of the four-chip HIGGS cell (data = 4 x model =
+    1; shards of 1024 x 28 rows; alpha over 10,500,000 rows) on the 2x2
+    host: the data-axis psum of the expansion gradient is the step's one
+    collective, an all-reduce of f32[1024], and the two Pallas passes keep
+    the names the cell's trace readers match (``mesh_pass.roofline``,
+    ``mesh.collective_share``)."""
+    import re
+    import sys
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed as dist
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+    from chipbench import meshtrace
+
+    n, d, rows = 10_500_000, 28, 1024
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    cfg = dsekl.DSEKLConfig(n_grad=rows, n_expand=rows, lam=1.0 / n,
+                            kernel_params=(("gamma", 0.0285),),
+                            schedule="inv_epoch", impl="pallas")
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    state = dist.ShardedDSEKLState(
+        arg((n,), jnp.float32, "model"), arg((n,), jnp.float32, "model"),
+        arg((), jnp.int32), arg((), jnp.int32))
+    step = dist.make_distributed_block_step(cfg, mesh, n)
+    text = step.jitted.lower(
+        arg((4 * rows, d), jnp.float32, "data", None),
+        arg((4 * rows,), jnp.float32, "data"),
+        arg((rows, d), jnp.float32, "model", None),
+        arg((rows,), jnp.int32, "model"), state,
+        arg((2,), jnp.uint32)).compile().as_text()
+    lines = [ln.strip() for ln in text.splitlines()]
+    reduce = [ln for ln in lines if re.search(meshtrace.ALL_REDUCE, ln)]
+    assert len(reduce) == 1, reduce
+    assert re.search(r"= f32\[1024\]\S* all-reduce\(", reduce[0]), reduce[0]
+    assert "replica_groups={{0,1,2,3}}" in reduce[0], reduce[0]
+    for pattern in (meshtrace.MATVEC, meshtrace.VECMAT):
+        kernel = [ln for ln in lines if re.search(pattern, ln)]
+        assert len(kernel) == 1, (pattern, kernel)
+        assert f"f32[{rows},{d}]" in kernel[0], kernel[0]

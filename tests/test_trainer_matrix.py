@@ -561,7 +561,8 @@ def test_mesh_elastic_rescale_subprocess():
             st = dist.ShardedDSEKLState(
                 alpha=jax.device_put(np.asarray(flat["alpha"]), sh),
                 accum=jax.device_put(np.asarray(flat["accum"]), sh),
-                step=jnp.asarray(flat["step"], jnp.int32))
+                step=jnp.asarray(flat["step"], jnp.int32),
+                epoch=jnp.asarray(flat["epoch"], jnp.int32))
             k = jnp.asarray(flat["key"])
             spe = max(256 // (cfg.n_grad * 2), 1)
             for e in range(3):
