@@ -468,11 +468,15 @@ class BlockPrefetcher:
     A worker thread fills one of ``depth`` (default 2, ping-pong)
     preallocated staging-buffer sets per step and — with ``to_device``
     (the default) — immediately issues the host-to-device transfer from
-    the staging buffer, blocking only ITSELF (never the consumer) until
-    the copy lands before recycling the buffer.  On GPU/TPU the staging
-    buffers would be pinned host memory and the transfers overlap device
-    compute on the copy stream; on CPU ``device_put`` copies
-    synchronously, so the same discipline holds trivially.
+    the staging buffer and hands the (possibly still landing) device
+    blocks to the consumer.  The runtime may read a staging buffer until
+    its copy lands, so the worker recycles a slot only after waiting for
+    its copies, and waits for them only once it has issued the NEXT
+    step's transfer: the copies land while it gathers, and the consumer
+    never waits on them on the host.  The staging buffers are plain numpy
+    arrays; on GPU/TPU the runtime copies out of them on its own
+    transfer threads.  On CPU, where ``device_put`` aliases host memory,
+    no staging buffers exist (see ``__init__``).
 
     The prefetcher is **multi-epoch**: the constructor's plan is only the
     first *segment*, and ``extend(plan_i, plan_j)`` queues further epochs
@@ -611,12 +615,24 @@ class BlockPrefetcher:
         try:
             import jax
             from jax.profiler import TraceAnnotation
+            # The slot whose transfer was issued last, with its blocks:
+            # the runtime may still be copying out of it, so it re-enters
+            # the free queue only after its copies have landed.
+            landing = None
+
+            def land(slot, blocks):
+                jax.block_until_ready(blocks)
+                self._free.put(slot)
+
             for idx_i, idx_j in self._next_indices():
                 bufs = None
                 if self._staging:
                     while bufs is None:
                         if self._stop:
                             return
+                        if landing is not None and self._free.empty():
+                            land(*landing)       # depth 1: the only slot
+                            landing = None
                         try:
                             bufs = self._free.get(timeout=0.05)
                         except queue.Empty:
@@ -628,12 +644,12 @@ class BlockPrefetcher:
                     if self._to_device:
                         with TraceAnnotation(self.H2D_SPAN):
                             item = self._transfer(host)
-                            # Wait for the DMA (worker-side only) so the
-                            # staging buffer is reusable the moment it
-                            # re-enters the free queue; the consumer never
-                            # blocks on a transfer.
-                            jax.block_until_ready(item)
-                        self._free.put(bufs)
+                            # The previous step's copies ran while this
+                            # step gathered: wait for them (worker-side
+                            # only) and recycle their slot.
+                            if landing is not None:
+                                land(*landing)
+                        landing = (bufs, item)
                     else:
                         item = bufs
                 else:
@@ -786,7 +802,7 @@ class MeshPrefetcher(BlockPrefetcher):
     blocks (``distributed.gather_mesh_blocks_from`` semantics: per-shard
     rows concatenated in shard order) and issues ``jax.device_put``
     STRAIGHT to the mesh step's shardings — so by the time the consumer
-    calls the step, every block is already placed and ``step_host``'s
+    calls the step, every block is placed (or landing) and ``step_host``'s
     device_put is a no-op: the H2D transfer leaves the critical path,
     exactly like the flat prefetcher's.  Worker/segment/staging
     machinery, stats, error propagation, and the multi-epoch ``extend``

@@ -5,6 +5,8 @@ local row-range views), the double-buffered ``BlockPrefetcher`` (ordering,
 staging-buffer safety, error propagation), and the host-side epoch plans
 reproducing exactly what the jitted in-memory epochs sample.
 """
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -383,6 +385,105 @@ def test_mesh_prefetcher_transfers_to_given_shardings(xy):
         blocks = p.get()
         for b, want in zip(blocks, sh):
             assert b.sharding == want
+
+
+# --- the accelerator staging path, run on the CPU ---------------------------
+
+class _LateCopy:
+    """A transfer that reads its host buffers only when it is waited on,
+    as an asynchronous copy may read them any time before it lands: a
+    slot refilled before its copy was waited for hands over the next
+    step's rows.  Each landing is logged as ("landed", step, thread)."""
+
+    def __init__(self, arrays, step, log):
+        self._src, self._step, self._log = arrays, step, log
+        self._out = None
+        self._lock = threading.Lock()
+
+    def block_until_ready(self):
+        with self._lock:
+            if self._out is None:
+                self._out = tuple(np.array(a) for a in self._src)
+                self._log.append(("landed", self._step,
+                                  threading.current_thread().name))
+        return self
+
+    def blocks(self):
+        return self.block_until_ready()._out
+
+
+def _late_copy_prefetcher(kind, xy, monkeypatch, depth):
+    """A prefetcher of ``kind`` ("flat" or "mesh") on the staging path
+    accelerators take, its transfers replaced by ``_LateCopy``; returns
+    it, the log, and the blocks each of its 2 x 6 steps must deliver."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    log = []
+
+    def transfer(self, arrays):
+        step = sum(1 for e in log if e[0] == "issued")
+        log.append(("issued", step, threading.current_thread().name))
+        return _LateCopy(arrays, step, log)
+
+    if kind == "flat":
+        x, y = xy
+        src = HostSource(x, y)
+        rng = np.random.default_rng(4)
+        segs = [(rng.integers(0, 97, (6, 16)), rng.integers(0, 97, (6, 12)))
+                for _ in range(2)]
+        want = [(x[pi], y[pi], x[pj]) for si, sj in segs
+                for pi, pj in zip(si, sj)]
+        cls = type("LateBlockPrefetcher", (BlockPrefetcher,),
+                   {"_transfer": transfer})
+        p = cls(src, *segs[0], depth=depth)
+    else:
+        _, ds, ms, plan_i, plan_j, sh = _mesh_fixture(xy)
+        segs = [(plan_i, plan_j), (plan_i[::-1], plan_j[::-1])]
+        with SyncMeshGather(ds, ms, sh, *segs[0]) as s:
+            s.extend(*segs[1])
+            want = [tuple(np.array(b) for b in s.get()) for _ in range(12)]
+        cls = type("LateMeshPrefetcher", (MeshPrefetcher,),
+                   {"_transfer": transfer})
+        p = cls(ds, ms, sh, *segs[0], depth=depth)
+    p.extend(*segs[1])
+    return p, log, want
+
+
+@pytest.mark.parametrize("kind,depth", [("flat", 1), ("flat", 2),
+                                        ("flat", 3), ("mesh", 1),
+                                        ("mesh", 2)])
+def test_staging_slot_reused_only_after_its_copy_landed(xy, monkeypatch,
+                                                        kind, depth):
+    """Over two extended segments, every step's blocks are read out of
+    their staging slot no earlier than the worker waits for them: the
+    consumer takes all 12 steps before reading one, so a slot refilled
+    before its copy landed would deliver another step's rows."""
+    p, log, want = _late_copy_prefetcher(kind, xy, monkeypatch, depth)
+    with p:
+        items = [p.get() for _ in range(12)]
+        assert p.stats()["steps"] == 12
+    for t, (item, w) in enumerate(zip(items, want)):
+        assert len(item.blocks()) == len(w)
+        for u, v in zip(item.blocks(), w):
+            np.testing.assert_array_equal(u, v, err_msg=f"step {t}")
+
+
+@pytest.mark.parametrize("kind", ["flat", "mesh"])
+def test_worker_waits_for_a_copy_after_issuing_the_next(xy, monkeypatch,
+                                                        kind):
+    """The worker gathers and issues step t+1 before it waits for step
+    t's copies, so they land while it gathers; the consumer never waits
+    for them."""
+    p, log, _ = _late_copy_prefetcher(kind, xy, monkeypatch, depth=2)
+    with p:
+        for _ in range(12):
+            p.get()
+        worker = p._thread.name
+    mine = [(e, k) for e, k, name in log if name == worker]
+    assert len(mine) == 12 + 11 and all(
+        name == worker for e, k, name in log)
+    for k in range(1, 12):
+        assert mine.index(("landed", k - 1)) > mine.index(("issued", k))
+    assert ("landed", 11) not in mine     # the last slot is still held
 
 
 # --- the global manifest + range-mapped sources (multi-host resume) ------
