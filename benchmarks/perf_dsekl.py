@@ -6,7 +6,7 @@ cell is MEMORY-bound.  Iterations replace it with the fused Pallas kernel
 (never materializes K), then tune the MXU dtype and BlockSpec tiling.  The
 Pallas kernels cannot execute on this CPU container, so each iteration's
 memory term comes from the kernel's exact analytic HBM-traffic model
-(kernels/dsekl/rbf_block.pass_hbm_bytes — a deterministic function of the
+(kernels/dsekl/block.pass_hbm_bytes — a deterministic function of the
 BlockSpecs) and its compute term from exact flop counting; correctness of
 every variant is asserted against ref.py in interpret mode by the test
 suite.  All terms use the same v5e constants as benchmarks/roofline.py.
@@ -21,7 +21,7 @@ from typing import Dict, List, Optional
 
 from benchmarks.load_harness import measure_multi_tenant
 from benchmarks.roofline import PEAK_FLOPS, HBM_BW, ICI_BW
-from repro.kernels.dsekl.rbf_block import choose_blocks, pass_hbm_bytes
+from repro.kernels.dsekl.block import choose_blocks, pass_hbm_bytes
 
 # dsekl_prod cell geometry (launch/dryrun.build_dsekl_cell).
 I_LOC = 8192

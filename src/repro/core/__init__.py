@@ -1,7 +1,7 @@
 """The paper's contribution: doubly stochastic empirical kernel learning."""
 from repro.core.dsekl import (  # noqa: F401
     DSEKLConfig, DSEKLState, init_state, step_serial, epoch_parallel,
-    grad_block, grad_block_parallel, apply_update, apply_update_parallel,
+    grad_block, apply_update,
     decision_function, decision_function_ref, decision_function_source,
     predict_labels, streaming_train_pass, support_vectors, truncate,
 )

@@ -282,7 +282,7 @@ def make_mesh_bcd_ops(cfg: DSEKLConfig, mesh, *, data_axis: str = "data",
     def _scatter_body(alpha_loc, idx_j, delta):
         # Global J -> this model shard's local rows; out-of-range
         # coordinates are dropped by the OOB scatter (the
-        # _local_block_step_precond pattern in core/distributed.py).
+        # correction scatter of _local_block_step in core/distributed.py).
         rows_m = alpha_loc.shape[0]
         local = idx_j - jax.lax.axis_index(model_axis) * rows_m
         safe = jnp.where((local >= 0) & (local < rows_m), local, rows_m)

@@ -140,26 +140,15 @@ def _shard_block_grad(cfg: DSEKLConfig, n_global: int, xi: Array, yi: Array,
 def _apply_shard_update(cfg: DSEKLConfig, alpha: Array, accum: Array,
                         step: Array, epoch: Array, idx_j: Array, g: Array
                         ) -> Tuple[Array, Array, Array]:
-    """Scatter one shard gradient into the local alpha/accum shard.
-
-    The rate is ``dsekl._lr`` of the step count t and the ``epoch`` the
-    step runs in, as in the serial plan: lr0 / t under ``inv_t``,
-    lr0 / epoch under ``inv_epoch``.
-
-    Like the single-device ``apply_update``/``apply_update_parallel``,
-    the AdaGrad accumulator is touched ONLY under ``schedule="adagrad"``
-    — non-adagrad mesh fits used to pay an extra O(N/shards) scatter per
-    step and checkpoint a silently mutated accumulator (alpha was
-    unaffected: the damp factor was ones)."""
-    t = step + 1
-    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, epoch))
-    if cfg.schedule == "adagrad":
-        accum = accum.at[idx_j].add(g * g)
-        damp = jax.lax.rsqrt(accum[idx_j])
-        alpha = alpha.at[idx_j].add(-lr * damp * g)
-    else:
-        alpha = alpha.at[idx_j].add(-lr * g)
-    return alpha, accum, t
+    """Scatter one shard gradient into the local alpha/accum shard:
+    ``dsekl.apply_update`` on the shard, so the rate is ``dsekl._lr`` of
+    the step count t and the ``epoch`` the step runs in, as in the serial
+    plan (lr0 / t under ``inv_t``, lr0 / epoch under ``inv_epoch``), and
+    the AdaGrad accumulator is touched ONLY under ``schedule="adagrad"``.
+    """
+    st = dsekl.apply_update(cfg, dsekl.DSEKLState(alpha, accum, step, epoch),
+                            idx_j, g)
+    return st.alpha, st.accum, st.step
 
 
 def _local_step(cfg: DSEKLConfig, n_global: int,
@@ -167,7 +156,8 @@ def _local_step(cfg: DSEKLConfig, n_global: int,
                 alpha: Array, accum: Array, step: Array, epoch: Array,
                 key: Array, *, data_axis: str, model_axis: str
                 ) -> Tuple[Array, Array, Array]:
-    """Per-device body (runs under shard_map): sample, gather, block step."""
+    """Per-device body (runs under shard_map): sample and gather, then
+    ``_local_block_step``."""
     d_id = jax.lax.axis_index(data_axis)
     m_id = jax.lax.axis_index(model_axis)
     # I decorrelated per data-shard; J per model-shard (same across the
@@ -176,71 +166,51 @@ def _local_step(cfg: DSEKLConfig, n_global: int,
     k_j = jax.random.fold_in(jax.random.fold_in(key, 1), m_id)
     idx_i = sampler.sample_uniform(k_i, x_grad.shape[0], cfg.n_grad)
     idx_j = sampler.sample_uniform(k_j, x_exp.shape[0], cfg.n_expand)
-
-    xi, yi = x_grad[idx_i], y_grad[idx_i]
-    xj, aj = x_exp[idx_j], alpha[idx_j]
-
-    g = _shard_block_grad(cfg, n_global, xi, yi, xj, aj, key,
-                          data_axis=data_axis, model_axis=model_axis)
-    with jax.named_scope("dsekl.mesh.update"):
-        return _apply_shard_update(cfg, alpha, accum, step, epoch, idx_j, g)
+    return _local_block_step(cfg, n_global, x_grad[idx_i], y_grad[idx_i],
+                             x_exp[idx_j], idx_j, alpha, accum, step, epoch,
+                             key, data_axis=data_axis, model_axis=model_axis)
 
 
 def _local_block_step(cfg: DSEKLConfig, n_global: int,
                       xi: Array, yi: Array, xj: Array, idx_j: Array,
                       alpha: Array, accum: Array, step: Array, epoch: Array,
-                      key: Array, *, data_axis: str, model_axis: str
+                      key: Array, pc: dsekl.PrecondBlock = None, *,
+                      data_axis: str, model_axis: str
                       ) -> Tuple[Array, Array, Array]:
     """Per-device body for PRE-GATHERED blocks (the out-of-core mesh step):
     the data plane supplies this shard's sampled gradient rows (xi, yi),
     this model shard's expansion rows (xj) and their LOCAL indices (idx_j);
-    only alpha/accum and the block math live on device."""
+    only alpha/accum and the block math live on device.
+
+    ``pc`` (replicated, GLOBAL indices) adds the EigenPro correction
+    (DESIGN.md §10): ``dsekl.precond_correction`` with the data-axis psum
+    completing c = K_{P, I_all} @ v_all = psum_data K_{P, I_d} @ v_d and
+    the step's J-union, every model shard's n_expand block.  delta is
+    identical on every device (v is built from the model-axis-psummed f),
+    so each model shard scatters the slice of it that it owns: global ids
+    are mapped to shard-local ones, with non-owned entries pushed out of
+    bounds — JAX drops out-of-bounds scatter updates, so no masking pass
+    is needed.  It is applied after the main update at the step's scalar
+    rate, as ``dsekl.apply_update`` applies it."""
     aj = alpha[idx_j]
-    g = _shard_block_grad(cfg, n_global, xi, yi, xj, aj, key,
-                          data_axis=data_axis, model_axis=model_axis)
+    if pc is None:
+        g = _shard_block_grad(cfg, n_global, xi, yi, xj, aj, key,
+                              data_axis=data_axis, model_axis=model_axis)
+    else:
+        g, v = _shard_block_grad_v(cfg, n_global, xi, yi, xj, aj, key,
+                                   data_axis=data_axis, model_axis=model_axis)
+        delta = dsekl.precond_correction(
+            cfg, xi, v, pc, xj.shape[0] * jax.lax.axis_size(model_axis),
+            c_reduce=functools.partial(jax.lax.psum, axis_name=data_axis))
     with jax.named_scope("dsekl.mesh.update"):
-        return _apply_shard_update(cfg, alpha, accum, step, epoch, idx_j, g)
-
-
-def _local_block_step_precond(cfg: DSEKLConfig, n_global: int,
-                              xi: Array, yi: Array, xj: Array, idx_j: Array,
-                              alpha: Array, accum: Array, step: Array,
-                              epoch: Array, key: Array, p_rows: Array,
-                              p_vecs: Array, p_damp: Array, p_idx: Array,
-                              *, data_axis: str, model_axis: str
-                              ) -> Tuple[Array, Array, Array]:
-    """``_local_block_step`` plus the EigenPro correction (DESIGN.md §10).
-
-    The preconditioner arrays arrive replicated (they are (m, ·)-shaped,
-    like any sampled block).  The correction vector
-
-        c = K_{P, I_all} @ v_all = psum_data K_{P, I_d} @ v_d
-        delta = V (q * (V^T c))                                  # (m,)
-
-    is identical on every device after the data-axis psum (v is built
-    from the model-axis-psummed f), so each model shard scatters the
-    slice of ``delta`` it owns: global ids are mapped to shard-local
-    ones, with non-owned entries pushed out of bounds — JAX drops
-    out-of-bounds scatter updates, so no masking pass is needed.
-    Applied after the main update with the step's scalar rate, exactly
-    like the single-device ``dsekl._apply_correction``."""
-    aj = alpha[idx_j]
-    g, v = _shard_block_grad_v(cfg, n_global, xi, yi, xj, aj, key,
-                               data_axis=data_axis, model_axis=model_axis)
-    c = kops.kernel_vecmat(xi, p_rows, v, kernel_name=cfg.kernel,
-                           kernel_params=cfg.kernel_params, impl=cfg.impl)
-    c = jax.lax.psum(c, data_axis)
-    # J-union of one mesh step: every model shard scatters its own
-    # n_expand block (axis size is static, so this folds to a constant).
-    j_union = xj.shape[0] * jax.lax.psum(1, model_axis)
-    delta = p_vecs @ ((j_union * p_damp) * (p_vecs.T @ c))
-    alpha, accum, t = _apply_shard_update(cfg, alpha, accum, step, epoch,
-                                          idx_j, g)
-    rows_m = alpha.shape[0]
-    local = p_idx - jax.lax.axis_index(model_axis) * rows_m
-    safe = jnp.where((local >= 0) & (local < rows_m), local, rows_m)
-    lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, epoch))
-    alpha = alpha.at[safe].add(lr * delta)      # OOB updates are dropped
+        alpha, accum, t = _apply_shard_update(cfg, alpha, accum, step, epoch,
+                                              idx_j, g)
+        if pc is not None:
+            rows_m = alpha.shape[0]
+            local = pc.indices - jax.lax.axis_index(model_axis) * rows_m
+            safe = jnp.where((local >= 0) & (local < rows_m), local, rows_m)
+            lr = dsekl._lr(cfg, dsekl.DSEKLState(alpha, accum, t, epoch))
+            alpha = alpha.at[safe].add(lr * delta)  # OOB updates are dropped
     return alpha, accum, t
 
 
@@ -319,60 +289,36 @@ def make_distributed_block_step(cfg: DSEKLConfig, mesh: Mesh, n_global: int,
             return a
         return jax.device_put(a, sh)
 
-    if precondition:
-        body = functools.partial(_local_block_step_precond, cfg, n_global,
-                                 data_axis=data_axis, model_axis=model_axis)
-        mapped = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
-                      P(model_axis), P(model_axis), P(model_axis), P(), P(),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(model_axis), P(model_axis), P()),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def step(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
-                 pc: dsekl.PrecondBlock):
-            alpha, accum, t = mapped(xi, yi, xj, idx_j, state.alpha,
-                                     state.accum, state.step, state.epoch,
-                                     key, pc.rows, pc.vectors, pc.damping,
-                                     pc.indices)
-            return ShardedDSEKLState(alpha, accum, t, state.epoch)
-
-        def step_host(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
-                      pc: dsekl.PrecondBlock):
-            pc_rep = jax.tree.map(lambda a: _put(a, rep_sh), pc)
-            return step(_put(xi, xi_sh), _put(yi, yi_sh), _put(xj, xj_sh),
-                        _put(idx_j, ij_sh), state, key, pc_rep)
-
-        step_host.jitted = step
-        step_host.shardings = shardings
-        return step_host
-
     body = functools.partial(_local_block_step, cfg, n_global,
                              data_axis=data_axis, model_axis=model_axis)
+    in_specs = (P(data_axis, None), P(data_axis), P(model_axis, None),
+                P(model_axis), P(model_axis), P(model_axis), P(), P(), P())
+    if precondition:
+        in_specs += (P(),)                      # the replicated PrecondBlock
     mapped = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(data_axis, None), P(data_axis), P(model_axis, None),
-                  P(model_axis), P(model_axis), P(model_axis), P(), P(), P()),
+        body, mesh=mesh, in_specs=in_specs,
         out_specs=(P(model_axis), P(model_axis), P()),
         check_vma=False,
     )
 
     @jax.jit
-    def step(xi, yi, xj, idx_j, state: ShardedDSEKLState, key):
+    def step(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
+             pc: dsekl.PrecondBlock = None):
         alpha, accum, t = mapped(xi, yi, xj, idx_j, state.alpha,
-                                 state.accum, state.step, state.epoch, key)
+                                 state.accum, state.step, state.epoch, key,
+                                 *(() if pc is None else (pc,)))
         return ShardedDSEKLState(alpha, accum, t, state.epoch)
 
-    def step_host(xi, yi, xj, idx_j, state: ShardedDSEKLState, key):
-        """Host-array front door: device_put the gathered blocks straight
-        to their shardings (one host-to-shards transfer each) — or pass
-        already-placed device blocks through untouched — then run the
-        compiled step."""
+    def step_host(xi, yi, xj, idx_j, state: ShardedDSEKLState, key,
+                  pc: dsekl.PrecondBlock = None):
+        """Host-array front door: device_put the gathered blocks (and the
+        preconditioner, replicated) straight to their shardings (one
+        host-to-shards transfer each) — or pass already-placed device
+        blocks through untouched — then run the compiled step."""
+        if pc is not None:
+            pc = jax.tree.map(lambda a: _put(a, rep_sh), pc)
         return step(_put(xi, xi_sh), _put(yi, yi_sh), _put(xj, xj_sh),
-                    _put(idx_j, ij_sh), state, key)
+                    _put(idx_j, ij_sh), state, key, pc)
 
     step_host.jitted = step
     step_host.shardings = shardings

@@ -11,14 +11,18 @@ Algorithm 2 (parallel, shared memory):  per epoch, fresh without-replacement
   across workers) and compute the block gradients; updates are dampened by
   the aggregated AdaGrad matrix  alpha <- alpha - lr * G^{-1/2} sum_k g^(k).
 
-Both are pure jittable functions over an explicit ``DSEKLState``; the
-distributed 2-D mesh variant lives in ``core/distributed.py`` and reuses the
-same block computation (``_block_f`` / ``_block_grad``).
+Both are pure jittable functions over an explicit ``DSEKLState`` and share
+one step core: Algorithm 2's step is Algorithm 1's block step over the
+union of its K worker blocks (``grad_block`` + ``apply_update``), with the
+EigenPro correction an optional term of both.  The distributed 2-D mesh
+variant lives in ``core/distributed.py`` and reuses the same block
+computation (``_block_f`` / ``_block_grad``, ``apply_update``,
+``precond_correction``).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,8 +65,9 @@ class DSEKLConfig:
     # Streaming dual pass (DESIGN.md §6): consume K_{I,J} in (row_block, |J|)
     # tiles instead of holding the whole |I| x |J| block — each tile is still
     # evaluated ONCE for both f and g.  0 = off (whole-block paths above);
-    # > 0 = the I row-block size for step_serial's ref path and the mesh
-    # step's fused form (peak kernel-block memory O(row_block * |J|)).
+    # > 0 = the I row-block size for the step core's ref path (either
+    # algorithm) and the mesh step's fused form (peak kernel-block memory
+    # O(row_block * |J|)).
     stream_row_block: int = 0
     # Training execution backend (core/trainer.py): "auto" resolves from
     # the data placement (mesh given -> mesh; host-resident DataSource ->
@@ -218,126 +223,85 @@ def _lr(cfg: DSEKLConfig, state: DSEKLState) -> Array:
 # ---------------------------------------------------------------------------
 # Block-parametrized step core (the out-of-core data plane, DESIGN.md §8).
 #
-# The jittable inner bodies of Algorithms 1 & 2, parametrized by PRE-GATHERED
+# The jittable inner body of Algorithms 1 & 2, parametrized by PRE-GATHERED
 # blocks instead of the whole dataset: compile cost is a function of
-# (n_grad, n_expand, D) only, so ONE compiled gradient core serves any N and
-# any dataset — the in-memory wrappers below trace through it unchanged
-# (bit-identical), and the host-resident DataSource path (data/source.py,
-# solver.fit) feeds it gathered blocks from storage.
+# (n_grad, |J|, D) only, so ONE compiled gradient core serves any N and
+# any dataset — the in-memory steps below trace through it, and the
+# host-resident DataSource path (data/source.py, solver.fit) feeds it
+# gathered blocks from storage.
+#
+# Algorithm 2's K workers jointly evaluate the kernel map over the union
+# of their disjoint expansion blocks: sum_k K_{I,J^k} a_{J^k} ==
+# K_{I,J_union} @ a_union, so its body IS Algorithm 1's over the union,
+# flattened to (K*j, D) / (K*j,) in worker order.  Every caller passes
+# whatever J its algorithm draws; the core never sees K.
 # ---------------------------------------------------------------------------
 
-def _grad_block_with_f(cfg: DSEKLConfig, xi: Array, yi: Array, xj: Array,
-                       aj: Array, n: int) -> Tuple[Array, Array]:
-    """``grad_block``'s body, also returning the decision values f_I.
+def grad_block(cfg: DSEKLConfig, xi: Array, yi: Array, xj: Array, aj: Array,
+               n: int = 0, pc: "PrecondBlock" = None,
+               j_union: Optional[int] = None
+               ) -> Tuple[Array, Optional[Array]]:
+    """Dual gradient g_J (incl. lam*alpha_J) for one gathered I-batch
+    against the J it is given, and the EigenPro correction of the step.
 
-    Every path below already produces f on the way to g (the fused op
-    emits both; the two-pass path needs f for the loss gradient), so
-    callers that discard it trace to the identical program — XLA drops
-    the unused output.  The preconditioned step keeps f to recompute the
-    loss gradient v for the correction term.
+    Shapes: xi (n_grad, D), yi (n_grad,), xj (|J|, D), aj (|J|,) — one
+    expansion block (Alg. 1) or the K-worker union (Alg. 2).  ``n`` is
+    consumed ONLY by ``cfg.unbiased_scaling`` (the N/|J| empirical-map
+    scale); with scaling off pass 0 so the jitted form never specializes
+    on the dataset size.  Returns ``(g, delta)``: ``delta`` is the
+    correction at ``pc``'s rows (``precond_correction`` scaled by
+    ``j_union``, default |J|), None when ``pc`` is None — the
+    unpreconditioned program.  Alg. 2 passes n_workers * n_expand, which
+    is |J| unless an epoch holds fewer expansion batches than workers.
     """
-    stream = (cfg.stream_row_block > 0
-              and kops.resolve_impl(cfg.impl, cfg.kernel) == "ref")
-    if stream:
+    if cfg.stream_row_block > 0 and kops.resolve_impl(cfg.impl,
+                                                      cfg.kernel) == "ref":
         # Streaming dual pass: K consumed in (row_block, |J|) tiles, each
         # evaluated once for f and g (the pallas backends stream in-kernel
         # already, so streaming only applies to the ref path).
         f, g = streaming_train_pass(cfg, xi, yi, xj, aj, n,
                                     row_block=cfg.stream_row_block)
-        return f, g + cfg.lam * aj
-    if cfg.fuse_dual_pass:
-        return _fused_f_and_grad(cfg, xi, yi, xj, aj, n)
-    f = _block_f(cfg, xi, xj, aj, n)
+        g = g + cfg.lam * aj
+    elif cfg.fuse_dual_pass:
+        f, g = _fused_f_and_grad(cfg, xi, yi, xj, aj, n)
+    else:
+        f = _block_f(cfg, xi, xj, aj, n)
+        v = losses_lib.get_loss(cfg.loss).grad_f(f, yi)
+        g = _block_grad(cfg, xi, xj, aj, v)
+    if pc is None:
+        return g, None
+    # Every branch produced f on the way to g; the correction needs the
+    # loss gradient v at the gradient rows.
     v = losses_lib.get_loss(cfg.loss).grad_f(f, yi)
-    return f, _block_grad(cfg, xi, xj, aj, v)
-
-
-def grad_block(cfg: DSEKLConfig, xi: Array, yi: Array, xj: Array, aj: Array,
-               n: int = 0) -> Array:
-    """Alg.-1 dual gradient g_J (incl. lam*alpha_J) for one gathered block.
-
-    Shapes: xi (n_grad, D), yi (n_grad,), xj (n_expand, D), aj (n_expand,).
-    ``n`` is consumed ONLY by ``cfg.unbiased_scaling`` (the N/|J| empirical-
-    map scale); with scaling off pass 0 so the jitted form never specializes
-    on the dataset size.
-    """
-    _, g = _grad_block_with_f(cfg, xi, yi, xj, aj, n)
-    return g
+    return g, precond_correction(cfg, xi, v, pc, j_union or xj.shape[0])
 
 
 def apply_update(cfg: DSEKLConfig, state: DSEKLState, idx_j: Array,
-                 g: Array) -> DSEKLState:
-    """Scatter one Alg.-1 block gradient into the O(N) state.
+                 g: Array, idx_p: Array = None,
+                 delta: Array = None) -> DSEKLState:
+    """Scatter one block gradient into the O(N) state, then the EigenPro
+    correction ``delta`` at ``idx_p`` (when given).
 
     The only N-shaped piece of a step — pure scatter/gather arithmetic, no
-    kernel work.  Compiled once per (N, n_expand); the expensive gradient
-    core above never sees N.
+    kernel work.  ``idx_j`` is the step's flat J (Alg. 2: the worker
+    union).  The rate is ``_lr`` at the incremented step.  The G_jj
+    accumulator is Alg. 2's AdaGrad matrix, touched ONLY under
+    ``schedule="adagrad"`` (lines 11 + 14: G_jj += g_j^2;
+    alpha -= lr * G^{-1/2} g); the correction takes the scalar rate alone
+    — it is its own preconditioner.
     """
     state = state._replace(step=state.step + 1)
+    lr = _lr(cfg, state)
+    accum = state.accum
     if cfg.schedule == "adagrad":
-        accum = state.accum.at[idx_j].add(g * g)
+        accum = accum.at[idx_j].add(g * g)
         damp = jax.lax.rsqrt(accum[idx_j])
-        alpha = state.alpha.at[idx_j].add(-_lr(cfg, state) * damp * g)
-        return state._replace(alpha=alpha, accum=accum)
-    alpha = state.alpha.at[idx_j].add(-_lr(cfg, state) * g)
-    return state._replace(alpha=alpha)
-
-
-def _grad_block_parallel_with_f(cfg: DSEKLConfig, xi: Array, yi: Array,
-                                xjk: Array, ajk: Array, n: int
-                                ) -> Tuple[Array, Array]:
-    """``grad_block_parallel``'s body, also returning f (see
-    ``_grad_block_with_f`` — identical program when f is discarded)."""
-    if cfg.fuse_dual_pass:
-        # The K disjoint worker blocks jointly evaluate the kernel map over
-        # their union: sum_k K_{I,J^k} a_{J^k} == K_{I,J_union} @ a_union.
-        # Flattening the worker axis turns the whole Alg. 2 inner body into
-        # ONE dual-pass op — each K_{I,J_union} tile is evaluated once for
-        # both f and the gradient (vs. twice on the two-pass path below).
-        xj_u = xjk.reshape(-1, xjk.shape[-1])           # (K*j, D)
-        aj_u = ajk.reshape(-1)                          # (K*j,)
-        return _fused_f_and_grad(cfg, xi, yi, xj_u, aj_u, n)
-    # Workers jointly evaluate the kernel map: f_i = sum_k K_{I,J^k} a_{J^k}.
-    # (vmap == the "in parallel on worker k" of Alg. 2; on a real pod this
-    # is the model-axis psum of core/distributed.py.)
-    f_parts = jax.vmap(lambda xj, aj: _block_f(cfg, xi, xj, aj, n))(xjk, ajk)
-    f = jnp.sum(f_parts, axis=0)
-    if cfg.unbiased_scaling:            # _block_f scaled by n/j; want n/(K*j)
-        f = f / xjk.shape[0]
-
-    v = losses_lib.get_loss(cfg.loss).grad_f(f, yi)
-    gk = jax.vmap(lambda xj, aj: _block_grad(cfg, xi, xj, aj, v))(xjk, ajk)
-    return f, gk.reshape(-1)
-
-
-def grad_block_parallel(cfg: DSEKLConfig, xi: Array, yi: Array, xjk: Array,
-                        ajk: Array, n: int = 0) -> Array:
-    """Alg.-2 inner-body gradient for one gathered I-batch against K gathered
-    worker expansion blocks.  xjk (K, j, D), ajk (K, j); returns the flat
-    (K*j,) gradient in worker order."""
-    _, flat_g = _grad_block_parallel_with_f(cfg, xi, yi, xjk, ajk, n)
-    return flat_g
-
-
-def apply_update_parallel(cfg: DSEKLConfig, state: DSEKLState, flat_j: Array,
-                          flat_g: Array) -> DSEKLState:
-    """Alg.-2 state update for one flat (K*j,) block gradient.
-
-    The G_jj accumulator is Alg. 2's AdaGrad matrix: like the serial
-    ``apply_update``, it is touched ONLY under ``schedule="adagrad"`` —
-    non-adagrad parallel fits used to pay an extra O(N) scatter per step
-    and checkpoint a silently mutated accumulator (alpha was unaffected:
-    the damp factor was ones).
-    """
-    state = state._replace(step=state.step + 1)
-    if cfg.schedule == "adagrad":
-        # Alg. 2 lines 11+14: G_jj += g_j^2 ; alpha -= lr * G^{-1/2} sum g^k.
-        accum = state.accum.at[flat_j].add(flat_g * flat_g)
-        damp = jax.lax.rsqrt(accum[flat_j])
-        alpha = state.alpha.at[flat_j].add(-_lr(cfg, state) * damp * flat_g)
-        return state._replace(alpha=alpha, accum=accum)
-    alpha = state.alpha.at[flat_j].add(-_lr(cfg, state) * flat_g)
-    return state._replace(alpha=alpha)
+        alpha = state.alpha.at[idx_j].add(-lr * damp * g)
+    else:
+        alpha = state.alpha.at[idx_j].add(-lr * g)
+    if delta is not None:
+        alpha = alpha.at[idx_p].add(lr * delta)
+    return state._replace(alpha=alpha, accum=accum)
 
 
 # ---------------------------------------------------------------------------
@@ -352,14 +316,14 @@ def apply_update_parallel(cfg: DSEKLConfig, state: DSEKLState, flat_j: Array,
 #     alpha_P += lr * delta                        # alongside alpha_J -= lr*g
 #
 # |J| is the step's J-union size (serial: n_expand; parallel: n_workers *
-# n_expand): the main update covers only |J|/n of the effective operator
-# per step in expectation while the correction fires deterministically, so
-# the |J| multiplier (the 1/n lives in q) makes the cancellation exact in
-# expectation.  K_{P,I} @ v is one kernel_vecmat over the gathered
-# preconditioner rows — the rows travel with the step exactly like the
-# expansion block, so the compiled shapes stay N-independent.
-# ``core/precond.py`` estimates the eigensystem and owns the auto
-# step-size rule.
+# n_expand; the mesh: n_model * n_expand): the main update covers only
+# |J|/n of the effective operator per step in expectation while the
+# correction fires deterministically, so the |J| multiplier (the 1/n lives
+# in q) makes the cancellation exact in expectation.  K_{P,I} @ v is one
+# kernel_vecmat over the gathered preconditioner rows — the rows travel
+# with the step exactly like the expansion block, so the compiled shapes
+# stay N-independent.  ``core/precond.py`` estimates the eigensystem and
+# owns the auto step-size rule.
 # ---------------------------------------------------------------------------
 
 class PrecondBlock(NamedTuple):
@@ -377,58 +341,19 @@ class PrecondBlock(NamedTuple):
 
 
 def precond_correction(cfg: DSEKLConfig, xi: Array, v: Array,
-                       pc: PrecondBlock, j_union: int) -> Array:
+                       pc: PrecondBlock, j_union: int,
+                       c_reduce=None) -> Array:
     """delta = U ((|J| q) * (U^T (K_{P,I} @ v))) — the EigenPro correction
     of one step's expected update (v = dloss/df at the gradient rows;
-    ``j_union`` the number of expansion coordinates the step scatters)."""
+    ``j_union`` the number of expansion coordinates the step scatters).
+    ``c_reduce`` completes K_{P,I} @ v over the gradient rows other
+    devices hold (the mesh's data-axis psum); ``None`` is the identity."""
     c = kops.kernel_vecmat(xi, pc.rows, v, kernel_name=cfg.kernel,
                            kernel_params=cfg.kernel_params, impl=cfg.impl)
+    if c_reduce is not None:
+        c = c_reduce(c)
     return pc.vectors @ ((float(j_union) * pc.damping)
                          * (pc.vectors.T @ c))
-
-
-def grad_block_precond(cfg: DSEKLConfig, xi: Array, yi: Array, xj: Array,
-                       aj: Array, pc: PrecondBlock, n: int = 0
-                       ) -> Tuple[Array, Array]:
-    """``grad_block`` plus the EigenPro correction: returns (g_J, delta)."""
-    f, g = _grad_block_with_f(cfg, xi, yi, xj, aj, n)
-    v = losses_lib.get_loss(cfg.loss).grad_f(f, yi)
-    return g, precond_correction(cfg, xi, v, pc, cfg.n_expand)
-
-
-def grad_block_parallel_precond(cfg: DSEKLConfig, xi: Array, yi: Array,
-                                xjk: Array, ajk: Array, pc: PrecondBlock,
-                                n: int = 0) -> Tuple[Array, Array]:
-    """``grad_block_parallel`` plus the EigenPro correction."""
-    f, flat_g = _grad_block_parallel_with_f(cfg, xi, yi, xjk, ajk, n)
-    v = losses_lib.get_loss(cfg.loss).grad_f(f, yi)
-    return flat_g, precond_correction(cfg, xi, v, pc,
-                                      cfg.n_workers * cfg.n_expand)
-
-
-def _apply_correction(cfg: DSEKLConfig, state: DSEKLState, idx_p: Array,
-                      delta: Array) -> DSEKLState:
-    """Scatter the correction with the step's scalar rate (the AdaGrad
-    per-coordinate damp applies to the main update only — the correction
-    is its own preconditioner).  Called AFTER the main apply, so ``_lr``
-    sees the same incremented step."""
-    alpha = state.alpha.at[idx_p].add(_lr(cfg, state) * delta)
-    return state._replace(alpha=alpha)
-
-
-def apply_update_precond(cfg: DSEKLConfig, state: DSEKLState, idx_j: Array,
-                         g: Array, idx_p: Array, delta: Array) -> DSEKLState:
-    """Alg.-1 scatter + the EigenPro correction scatter."""
-    return _apply_correction(cfg, apply_update(cfg, state, idx_j, g),
-                             idx_p, delta)
-
-
-def apply_update_parallel_precond(cfg: DSEKLConfig, state: DSEKLState,
-                                  flat_j: Array, flat_g: Array, idx_p: Array,
-                                  delta: Array) -> DSEKLState:
-    """Alg.-2 scatter + the EigenPro correction scatter."""
-    return _apply_correction(
-        cfg, apply_update_parallel(cfg, state, flat_j, flat_g), idx_p, delta)
 
 
 def scale_n(cfg: DSEKLConfig, n: int) -> int:
@@ -440,19 +365,11 @@ def scale_n(cfg: DSEKLConfig, n: int) -> int:
 
 # Jitted entry points for host-driven (out-of-core) steps.  ``n`` is static
 # but callers pass ``scale_n(cfg, n)`` — 0 unless unbiased_scaling, so the
-# compile cache is keyed on (cfg, n_grad, n_expand, D) only and N never
+# compile cache is keyed on (cfg, n_grad, |J|, D) only and N never
 # retraces the kernel work (tests/test_outofcore_training.py asserts the
-# compile count).  The N-shaped scatter lives in the separate apply jits.
-grad_block_jit = jax.jit(grad_block, static_argnames=("cfg", "n"))
-apply_update_jit = jax.jit(apply_update, static_argnames=("cfg",))
-grad_block_parallel_jit = jax.jit(grad_block_parallel,
-                                  static_argnames=("cfg", "n"))
-apply_update_parallel_jit = jax.jit(apply_update_parallel,
-                                    static_argnames=("cfg",))
-grad_block_precond_jit = jax.jit(grad_block_precond,
-                                 static_argnames=("cfg", "n"))
-grad_block_parallel_precond_jit = jax.jit(grad_block_parallel_precond,
-                                          static_argnames=("cfg", "n"))
+# compile count).  The N-shaped scatter is a separate dispatch
+# (``trainer._apply_then_gather``).
+grad_block_jit = jax.jit(grad_block, static_argnames=("cfg", "n", "j_union"))
 
 
 # ---------------------------------------------------------------------------
@@ -500,73 +417,41 @@ def pad_lanes(x: Array) -> PaddedRows:
 
 
 # ---------------------------------------------------------------------------
-# Algorithm 1 — serial doubly stochastic kernel learning.
+# Algorithms 1 & 2 on device-resident data.
 # ---------------------------------------------------------------------------
+
+def _step(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
+          idx_i: Array, idx_j: Array, pc: PrecondBlock = None,
+          j_union: Optional[int] = None) -> DSEKLState:
+    """Gather, train pass, update for ONE I-batch against a flat J index
+    (Alg. 1's expansion block or Alg. 2's worker union) — the in-memory
+    wrapper over the block-parametrized core.  x (N, D) or its
+    ``PaddedRows``."""
+    n = x.shape[0]
+    with jax.named_scope("dsekl.gather"):
+        xi, yi = x[idx_i], y[idx_i]
+        xj, aj = x[idx_j], state.alpha[idx_j]
+    with jax.named_scope("dsekl.train_pass"):
+        g, delta = grad_block(cfg, xi, yi, xj, aj, scale_n(cfg, n), pc,
+                              j_union)
+    with jax.named_scope("dsekl.update"):
+        return apply_update(cfg, state, idx_j, g,
+                            None if pc is None else pc.indices, delta)
+
 
 def step_serial(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
                 key: Array, pc: PrecondBlock = None) -> DSEKLState:
-    """One Alg.-1 iteration.  x (N, D) or its ``PaddedRows``, y (N,).
-
-    Thin in-memory wrapper over the block-parametrized core: gather the
-    sampled blocks on device, compute the block gradient, scatter.  With
-    ``pc=None`` (the default) this traces to exactly the pre-refactor
-    program (bit-identical outputs); a ``PrecondBlock`` adds the EigenPro
-    correction after the dual pass.
+    """One Alg.-1 iteration: draw I and J uniformly with replacement, then
+    one block step.  x (N, D) or its ``PaddedRows``, y (N,).  With
+    ``pc=None`` (the default) no EigenPro term is traced; a
+    ``PrecondBlock`` adds the correction after the dual pass.
     """
     n = x.shape[0]
     with jax.named_scope("dsekl.sample"):
         ki, kj = jax.random.split(key)
         idx_i = sampler.sample_uniform(ki, n, cfg.n_grad)
         idx_j = sampler.sample_uniform(kj, n, cfg.n_expand)
-
-    with jax.named_scope("dsekl.gather"):
-        xi, yi = x[idx_i], y[idx_i]
-        xj, aj = x[idx_j], state.alpha[idx_j]
-
-    if pc is None:
-        with jax.named_scope("dsekl.train_pass"):
-            g = grad_block(cfg, xi, yi, xj, aj, scale_n(cfg, n))
-        with jax.named_scope("dsekl.update"):
-            return apply_update(cfg, state, idx_j, g)
-    with jax.named_scope("dsekl.train_pass"):
-        g, delta = grad_block_precond(cfg, xi, yi, xj, aj, pc,
-                                      scale_n(cfg, n))
-    with jax.named_scope("dsekl.update"):
-        return apply_update_precond(cfg, state, idx_j, g, pc.indices, delta)
-
-
-# ---------------------------------------------------------------------------
-# Algorithm 2 — parallel shared-memory variant.
-# ---------------------------------------------------------------------------
-
-def _parallel_inner(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
-                    idx_i: Array, idx_jk: Array,
-                    pc: PrecondBlock = None) -> DSEKLState:
-    """Process ONE gradient batch against K expansion batches (Alg. 2 body).
-
-    idx_i (i_batch,);  idx_jk (K, j_batch) — disjoint worker batches;
-    x (N, D) or its ``PaddedRows``.  Thin in-memory wrapper over the
-    block-parametrized core.
-    """
-    n = x.shape[0]
-    with jax.named_scope("dsekl.gather"):
-        xi, yi = x[idx_i], y[idx_i]
-        xjk = x[idx_jk]                 # (K, j, D)
-        ajk = state.alpha[idx_jk]       # (K, j)
-    flat_j = idx_jk.reshape(-1)
-
-    if pc is None:
-        with jax.named_scope("dsekl.train_pass"):
-            flat_g = grad_block_parallel(cfg, xi, yi, xjk, ajk,
-                                         scale_n(cfg, n))
-        with jax.named_scope("dsekl.update"):
-            return apply_update_parallel(cfg, state, flat_j, flat_g)
-    with jax.named_scope("dsekl.train_pass"):
-        flat_g, delta = grad_block_parallel_precond(cfg, xi, yi, xjk, ajk,
-                                                    pc, scale_n(cfg, n))
-    with jax.named_scope("dsekl.update"):
-        return apply_update_parallel_precond(cfg, state, flat_j, flat_g,
-                                             pc.indices, delta)
+    return _step(cfg, state, x, y, idx_i, idx_j, pc)
 
 
 def epoch_parallel(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
@@ -574,7 +459,8 @@ def epoch_parallel(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
     """One epoch of Alg. 2: without-replacement batches, scan over I-batches.
 
     The number of I-batches is floor(N / n_grad); each consumes K = n_workers
-    expansion batches of size n_expand, cycled without replacement.
+    expansion batches of size n_expand, cycled without replacement, as one
+    flat J union.
     """
     n = x.shape[0]
     state = state._replace(epoch=state.epoch + 1)
@@ -593,8 +479,9 @@ def epoch_parallel(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
     def body(st, ib_and_assign):
         idx_i, a = ib_and_assign
         with jax.named_scope("dsekl.sample"):
-            idx_jk = j_batches[a]                                 # (K, j)
-        return _parallel_inner(cfg, st, x, y, idx_i, idx_jk, pc), ()
+            idx_j = j_batches[a].reshape(-1)                      # (K*j,)
+        return _step(cfg, st, x, y, idx_i, idx_j, pc,
+                     cfg.n_workers * cfg.n_expand), ()
 
     state, _ = jax.lax.scan(body, state, (i_batches, assign))
     return state

@@ -122,22 +122,16 @@ def _epoch_serial(cfg: DSEKLConfig, state: DSEKLState, x: Array, y: Array,
 _epoch_parallel = jax.jit(dsekl.epoch_parallel, static_argnames=("cfg",))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "parallel"))
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def _apply_then_gather(cfg: DSEKLConfig, state: DSEKLState, idx_j: Array,
                        g: Array, idx_next: Array,
                        idx_p: Optional[Array] = None,
-                       delta: Optional[Array] = None, *,
-                       parallel: bool = False):
-    """Fold the O(N) scatter of step t and the alpha gather of step t+1
-    into ONE dispatch — the only two N-shaped ops of a hosted step.  The
-    single block-apply helper every plan shares; ``parallel`` picks the
-    Alg.-1 or Alg.-2 scatter core (the only difference between them).
-    ``idx_p``/``delta`` fold the EigenPro correction scatter into the
-    same dispatch (None — the default — traces to the old program)."""
-    apply_fn = dsekl.apply_update_parallel if parallel else dsekl.apply_update
-    state = apply_fn(cfg, state, idx_j, g)
-    if delta is not None:
-        state = dsekl._apply_correction(cfg, state, idx_p, delta)
+                       delta: Optional[Array] = None):
+    """Fold the O(N) scatter of step t (``dsekl.apply_update``, with the
+    EigenPro correction when ``delta`` is given) and the alpha gather of
+    step t+1 into ONE dispatch — the only two N-shaped ops of a hosted
+    step."""
+    state = dsekl.apply_update(cfg, state, idx_j, g, idx_p, delta)
     return state, state.alpha[idx_next]
 
 
@@ -423,8 +417,9 @@ class HostedPlan(ExecutionPlan):
         if any(q[0] == kb for q in self._queued):
             return                              # already planned ahead
         plan_i, plan_j = self._build_plan(key)
-        # Explicit flat width: reshape(0, -1) is ambiguous for the empty
-        # epoch plan (N < n_grad on the parallel path).
+        # One flat J per step (Alg. 2: the K-worker union).  Explicit flat
+        # width: reshape(0, -1) is ambiguous for the empty epoch plan
+        # (N < n_grad on the parallel path).
         flat_j = plan_j.reshape(plan_i.shape[0],
                                 int(np.prod(plan_j.shape[1:], dtype=int)))
         if self._loader is None:
@@ -432,7 +427,7 @@ class HostedPlan(ExecutionPlan):
             self._loader = cls(self.source, plan_i, flat_j)
         else:
             self._loader.extend(plan_i, flat_j)
-        self._queued.append((kb, plan_i, plan_j))
+        self._queued.append((kb, plan_i, flat_j))
 
     def _pop_plan(self, key: Array):
         kb = np.asarray(key).tobytes()
@@ -457,40 +452,20 @@ class HostedPlan(ExecutionPlan):
         n_eff = dsekl.scale_n(cfg, self.n)
         loader = self._loader
         pc = self.precond
-        if self.algorithm == "serial":
-            aj = state.alpha[jnp.asarray(plan_j[0])]
-            for t in range(steps):
-                xi, yi, xj = loader.get()
-                nxt = plan_j[min(t + 1, steps - 1)]
-                if pc is None:
-                    g = dsekl.grad_block_jit(cfg, xi, yi, xj, aj, n_eff)
-                    state, aj = _apply_then_gather(
-                        cfg, state, plan_j[t], g, nxt, parallel=False)
-                else:
-                    g, delta = dsekl.grad_block_precond_jit(
-                        cfg, xi, yi, xj, aj, pc, n_eff)
-                    state, aj = _apply_then_gather(
-                        cfg, state, plan_j[t], g, nxt, pc.indices, delta,
-                        parallel=False)
-        else:
-            n_i, k, j = plan_j.shape
-            flat = plan_j.reshape(n_i, k * j)
-            ajk = state.alpha[jnp.asarray(plan_j[0])]
-            for b in range(steps):
-                xi, yi, xj_flat = loader.get()
-                xjk = jnp.asarray(xj_flat).reshape(k, j, self.source.d)
-                nxt = plan_j[min(b + 1, steps - 1)]
-                if pc is None:
-                    flat_g = dsekl.grad_block_parallel_jit(
-                        cfg, xi, yi, xjk, ajk, n_eff)
-                    state, ajk = _apply_then_gather(
-                        cfg, state, flat[b], flat_g, nxt, parallel=True)
-                else:
-                    flat_g, delta = dsekl.grad_block_parallel_precond_jit(
-                        cfg, xi, yi, xjk, ajk, pc, n_eff)
-                    state, ajk = _apply_then_gather(
-                        cfg, state, flat[b], flat_g, nxt, pc.indices, delta,
-                        parallel=True)
+        idx_p = None if pc is None else pc.indices
+        # The EigenPro scale of the in-memory epochs (dsekl.epoch_parallel).
+        j_union = (cfg.n_workers * cfg.n_expand
+                   if self.algorithm == "parallel" else None)
+        aj = state.alpha[jnp.asarray(plan_j[0])]
+        for t in range(steps):
+            # xj: one expansion block (Alg. 1) or the K-worker union
+            # (Alg. 2), in plan_j's flat order.
+            xi, yi, xj = loader.get()
+            g, delta = dsekl.grad_block_jit(cfg, xi, yi, xj, aj, n_eff, pc,
+                                            j_union)
+            state, aj = _apply_then_gather(
+                cfg, state, plan_j[t], g, plan_j[min(t + 1, steps - 1)],
+                idx_p, delta)
         state.alpha.block_until_ready()         # epoch-boundary sync
         self._consumed_steps += steps
         return state
@@ -654,10 +629,7 @@ class MeshPlan(ExecutionPlan):
                 xi, yi, xj, idx_j = loader.get()
             with TraceAnnotation("dsekl.mesh.step"):
                 k = jnp.asarray(step_keys[t])
-                if pc is None:
-                    sh = self.step_host(xi, yi, xj, idx_j, sh, k)
-                else:
-                    sh = self.step_host(xi, yi, xj, idx_j, sh, k, pc)
+                sh = self.step_host(xi, yi, xj, idx_j, sh, k, pc)
         sh.alpha.block_until_ready()            # epoch-boundary sync
         self._consumed_steps += self.steps_per_epoch
         return DSEKLState(alpha=sh.alpha, accum=sh.accum, step=sh.step,

@@ -1,6 +1,6 @@
 """Fused empirical-kernel-map ops as Pallas TPU kernels — all kernels.
 
-This generalizes ``rbf_block.py`` (the original RBF-only path) in two ways:
+Two things over a plain per-kernel matvec:
 
 1. **Multi-kernel tiles.**  A static registry ``TILE_FNS`` maps every kernel
    in ``core/kernels_fn.KERNELS`` (rbf, laplacian, linear, polynomial,

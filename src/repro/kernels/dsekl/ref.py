@@ -1,7 +1,7 @@
 """Pure-jnp oracles for the fused DSEKL kernel ops.
 
 These are the semantic definition of the two hot-spot ops; the Pallas
-kernels in ``rbf_block.py`` must match them (tests sweep shapes/dtypes and
+kernels in ``block.py`` must match them (tests sweep shapes/dtypes and
 ``assert_allclose`` against these).
 """
 from __future__ import annotations

@@ -6,14 +6,23 @@ The fused path (cfg.fuse_dual_pass=True, the default) must:
   * track the two-pass path's state trajectory over 50 steps at tolerance
     (on the ref backend the serial fused step is the *same* float program —
     K evaluated once instead of twice — so agreement is essentially exact;
-    the parallel path re-associates the worker sum, hence the tolerance).
+    the parallel epoch evaluates the workers' union in one block, hence
+    the tolerance).
+
+Alg. 2's epoch is the block core over the flattened union of its K worker
+blocks: a hand loop of ``grad_block`` + ``apply_update`` over that union
+reproduces it bit for bit, and the per-worker two-pass sum (the paper's
+"K workers jointly evaluate the kernel map", kept here as an oracle)
+matches it at tolerance.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DSEKLConfig, dsekl, error_rate, fit
+from repro.core import DSEKLConfig, dsekl, error_rate, fit, losses, sampler
 from repro.data import make_xor, train_test_split
+from repro.kernels.dsekl import ops as kops
 
 
 @pytest.fixture(scope="module")
@@ -71,8 +80,9 @@ def test_fused_serial_matches_two_pass_50_steps(xor_split, schedule):
 
 
 def test_fused_parallel_epoch_matches_two_pass(xor_split):
-    """One Alg. 2 epoch: the fused union-block evaluation re-associates the
-    per-worker sums, so agreement is at (tight) float tolerance."""
+    """Three Alg. 2 epochs: the fused union-block evaluation against the
+    two-pass one (matvec, then vecmat, over the same union) at (tight)
+    float tolerance."""
     xtr, ytr, _, _ = xor_split
     cfg_f = CFG.replace(n_workers=4)
     cfg_2 = cfg_f.replace(fuse_dual_pass=False)
@@ -86,6 +96,79 @@ def test_fused_parallel_epoch_matches_two_pass(xor_split):
     np.testing.assert_allclose(np.asarray(st_f.alpha), np.asarray(st_2.alpha),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(st_f.accum), np.asarray(st_2.accum),
+                               rtol=1e-4, atol=1e-4)
+
+
+def _per_worker_two_pass(cfg, xi, yi, xjk, ajk):
+    """The Alg.-2 gradient as its K workers compute it: each worker's
+    partial decision values K_{I,J^k} a_{J^k}, summed; then each worker's
+    block gradient K_{I,J^k}^T v + lam a_{J^k}, in worker order."""
+    kw = dict(kernel_name=cfg.kernel, kernel_params=cfg.kernel_params,
+              impl=cfg.impl)
+    f = sum(kops.kernel_matvec(xi, xj, aj, **kw) for xj, aj in zip(xjk, ajk))
+    v = losses.get_loss(cfg.loss).grad_f(f, yi)
+    return jnp.concatenate([kops.kernel_vecmat(xi, xj, v, **kw) + cfg.lam * aj
+                            for xj, aj in zip(xjk, ajk)])
+
+
+def _hand_parallel_epochs(cfg, x, y, key, grad_fn, epochs=3):
+    """``epochs`` Alg.-2 epochs as a Python loop over the epoch plan
+    ``dsekl.epoch_parallel`` samples, one ``apply_update`` per I-batch
+    over the flat worker union."""
+    st = dsekl.init_state(x.shape[0])
+    apply = jax.jit(dsekl.apply_update, static_argnames=("cfg",))
+    for _ in range(epochs):
+        key, sub = jax.random.split(key)
+        st = st._replace(epoch=st.epoch + 1)
+        i_batches, idx_jk = sampler.parallel_epoch_plan(
+            sub, x.shape[0], cfg.n_grad, cfg.n_expand, cfg.n_workers)
+        for idx_i, jk in zip(i_batches, idx_jk):
+            idx_j = jk.reshape(-1)
+            g = grad_fn(cfg, x[idx_i], y[idx_i], x[jk], st.alpha[jk])
+            st = apply(cfg, st, idx_j, g)
+    return st
+
+
+def _parallel_epochs(cfg, x, y, key, epochs=3):
+    st = dsekl.init_state(x.shape[0])
+    epoch = jax.jit(dsekl.epoch_parallel, static_argnames=("cfg",))
+    for _ in range(epochs):
+        key, sub = jax.random.split(key)
+        st = epoch(cfg, st, x, y, sub)
+    return st
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "two_pass"])
+def test_parallel_epoch_is_the_block_core_over_the_worker_union(xor_split,
+                                                                fuse):
+    """``epoch_parallel`` == ``grad_block`` + ``apply_update`` over the
+    flattened union of the K worker blocks, bit for bit."""
+    xtr, ytr, _, _ = xor_split
+    cfg = CFG.replace(n_workers=3, fuse_dual_pass=fuse, impl="ref")
+    core = jax.jit(lambda cfg, xi, yi, xjk, ajk: dsekl.grad_block(
+        cfg, xi, yi, xjk.reshape(-1, xjk.shape[-1]), ajk.reshape(-1))[0],
+        static_argnames=("cfg",))
+    want = _parallel_epochs(cfg, xtr, ytr, jax.random.PRNGKey(5))
+    got = _hand_parallel_epochs(cfg, xtr, ytr, jax.random.PRNGKey(5), core)
+    for name in ("alpha", "accum", "step", "epoch"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(want, name)), name)
+
+
+@pytest.mark.parametrize("fuse", [True, False], ids=["fused", "two_pass"])
+def test_union_core_matches_the_per_worker_two_pass_sum(xor_split, fuse):
+    """Three Alg. 2 epochs of the union core against the per-worker
+    two-pass oracle, at ``test_fused_parallel_epoch_matches_two_pass``'s
+    tolerances."""
+    xtr, ytr, _, _ = xor_split
+    cfg = CFG.replace(n_workers=4, fuse_dual_pass=fuse, impl="ref")
+    want = _hand_parallel_epochs(cfg, xtr, ytr, jax.random.PRNGKey(5),
+                                 jax.jit(_per_worker_two_pass,
+                                         static_argnames=("cfg",)))
+    got = _parallel_epochs(cfg, xtr, ytr, jax.random.PRNGKey(5))
+    np.testing.assert_allclose(np.asarray(got.alpha), np.asarray(want.alpha),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.accum), np.asarray(want.accum),
                                rtol=1e-4, atol=1e-4)
 
 

@@ -5,7 +5,7 @@ Four independent bugs, each pinned by a regression test:
   * an early-converged fit whose convergence epoch was off the
     ``eval_every`` cadence ended with NO ``val_error`` in its final
     history record;
-  * ``apply_update_parallel`` (and the mesh ``_apply_shard_update``)
+  * the Alg.-2 update (and the mesh ``_apply_shard_update``)
     scattered ``g*g`` into the AdaGrad accumulator on EVERY schedule —
     non-adagrad fits paid an extra O(N) scatter per step and
     checkpointed a silently mutated accumulator;
@@ -68,19 +68,20 @@ def test_convergence_epoch_off_eval_cadence_still_evaluates():
 
 @pytest.mark.parametrize("schedule", ["inv_t", "inv_epoch", "const"])
 def test_parallel_apply_leaves_accum_untouched_off_adagrad(schedule):
-    """Non-adagrad parallel updates must not mutate the accumulator.
+    """Non-adagrad parallel updates (``apply_update`` over a two-worker
+    J union) must not mutate the accumulator.
 
-    DELIBERATE semantic change: the old ``apply_update_parallel``
-    scattered ``g*g`` into accum on every schedule (alpha was unaffected
-    — the damp factor was ones), so a checkpoint of a non-adagrad
-    parallel fit pinned a mutated accumulator.  No shipped fixture
-    relied on it; new checkpoints hold the pristine init (all ones).
+    DELIBERATE semantic change: the old Alg.-2 update scattered ``g*g``
+    into accum on every schedule (alpha was unaffected — the damp factor
+    was ones), so a checkpoint of a non-adagrad parallel fit pinned a
+    mutated accumulator.  No shipped fixture relied on it; new
+    checkpoints hold the pristine init (all ones).
     """
     cfg = CFG.replace(schedule=schedule, n_workers=2)
     st = init_state(128)
     flat_j = jnp.arange(32)
     flat_g = jnp.linspace(-1.0, 1.0, 32)
-    out = dsekl.apply_update_parallel(cfg, st, flat_j, flat_g)
+    out = dsekl.apply_update(cfg, st, flat_j, flat_g)
     assert np.array_equal(np.asarray(out.accum), np.ones(128))
     assert not np.array_equal(np.asarray(out.alpha), np.zeros(128))
 
@@ -90,22 +91,32 @@ def test_parallel_apply_adagrad_still_accumulates():
     st = init_state(128)
     flat_j = jnp.arange(32)
     flat_g = jnp.full((32,), 2.0)
-    out = dsekl.apply_update_parallel(cfg, st, flat_j, flat_g)
+    out = dsekl.apply_update(cfg, st, flat_j, flat_g)
     expect = np.ones(128)
     expect[:32] += 4.0
     np.testing.assert_allclose(np.asarray(out.accum), expect)
 
 
 def test_serial_and_parallel_accum_contract_match():
-    """Serial and parallel applies agree on WHEN accum is touched."""
-    for schedule in ("adagrad", "inv_t", "const"):
-        cfg = CFG.replace(schedule=schedule)
-        st = init_state(64)
-        idx = jnp.arange(16)
-        g = jnp.ones((16,))
-        a_ser = dsekl.apply_update(cfg, st, idx, g).accum
-        a_par = dsekl.apply_update_parallel(cfg, st, idx, g).accum
-        np.testing.assert_array_equal(np.asarray(a_ser), np.asarray(a_par))
+    """The single-device update and the mesh shard update agree bit for
+    bit, on every schedule: the same alpha, accum and step count."""
+    from repro.core import distributed as dist
+
+    for schedule in ("adagrad", "inv_t", "inv_epoch", "const"):
+        cfg = CFG.replace(schedule=schedule, lr0=0.7)
+        st = init_state(64)._replace(step=jnp.asarray(4, jnp.int32),
+                                     epoch=jnp.asarray(3, jnp.int32))
+        st = st._replace(accum=st.accum + jnp.linspace(0.0, 2.0, 64))
+        idx = jnp.arange(0, 64, 4)
+        g = jnp.linspace(-1.5, 2.0, 16)
+        want = dsekl.apply_update(cfg, st, idx, g)
+        alpha, accum, t = dist._apply_shard_update(
+            cfg, st.alpha, st.accum, st.step, st.epoch, idx, g)
+        np.testing.assert_array_equal(np.asarray(alpha),
+                                      np.asarray(want.alpha))
+        np.testing.assert_array_equal(np.asarray(accum),
+                                      np.asarray(want.accum))
+        assert int(t) == int(want.step) == 5
 
 
 # ---------------------------------------------------------------------------

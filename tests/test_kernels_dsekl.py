@@ -7,7 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import kernels_fn
-from repro.kernels.dsekl import ref, rbf_block
+from repro.kernels.dsekl import block, ref
 from repro.kernels.dsekl import ops as kops
 
 
@@ -32,8 +32,9 @@ def test_matvec_matches_ref(shape, dtype):
     kern = kernels_fn.get_kernel("rbf", gamma=0.7)
     want = ref.ref_kernel_matvec(kern, x.astype(jnp.float32),
                                  z.astype(jnp.float32), a.astype(jnp.float32))
-    got = rbf_block.rbf_matvec_pallas(x, z, a, gamma=0.7, interpret=True,
-                                      block_i=64, block_j=64)
+    got = block.kernel_matvec_pallas(x, z, a, kernel_name="rbf",
+                                     params={"gamma": 0.7}, interpret=True,
+                                     block_i=64, block_j=64)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
@@ -50,8 +51,9 @@ def test_vecmat_matches_ref(shape, dtype):
     kern = kernels_fn.get_kernel("rbf", gamma=0.7)
     want = ref.ref_kernel_vecmat(kern, x.astype(jnp.float32),
                                  z.astype(jnp.float32), v.astype(jnp.float32))
-    got = rbf_block.rbf_vecmat_pallas(x, z, v, gamma=0.7, interpret=True,
-                                      block_i=64, block_j=64)
+    got = block.kernel_vecmat_pallas(x, z, v, kernel_name="rbf",
+                                     params={"gamma": 0.7}, interpret=True,
+                                     block_i=64, block_j=64)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
@@ -63,8 +65,9 @@ def test_block_shape_invariance():
     x = jax.random.normal(k1, (200, 17))
     z = jax.random.normal(k2, (150, 17))
     a = jax.random.normal(k3, (150,))
-    outs = [rbf_block.rbf_matvec_pallas(x, z, a, gamma=1.0, interpret=True,
-                                        block_i=bi, block_j=bj)
+    outs = [block.kernel_matvec_pallas(x, z, a, kernel_name="rbf",
+                                       params={"gamma": 1.0}, interpret=True,
+                                       block_i=bi, block_j=bj)
             for bi, bj in [(64, 64), (128, 128), (32, 128)]]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
@@ -81,17 +84,18 @@ def test_bf16_mxu_path_accuracy():
     gamma = 0.5 / 54          # O(1) kernel values (median sq dist ~ 2D)
     kern = kernels_fn.get_kernel("rbf", gamma=gamma)
     want = ref.ref_kernel_matvec(kern, x, z, a)
-    got = rbf_block.rbf_matvec_pallas(x, z, a, gamma=gamma, interpret=True,
-                                      mxu_dtype=jnp.bfloat16,
-                                      block_i=128, block_j=128)
+    got = block.kernel_matvec_pallas(x, z, a, kernel_name="rbf",
+                                     params={"gamma": gamma}, interpret=True,
+                                     mxu_dtype=jnp.bfloat16,
+                                     block_i=128, block_j=128)
     rel = float(jnp.abs(want - got).max() / jnp.abs(want).max())
     assert rel < 0.01, rel
 
 
 def test_choose_blocks_vmem_budget():
     from repro.kernels.dsekl.block import tile_vmem_bytes
-    from repro.kernels.dsekl.rbf_block import (choose_blocks, pass_hbm_bytes,
-                                               VMEM_BUDGET)
+    from repro.kernels.dsekl.block import (choose_blocks, pass_hbm_bytes,
+                                           VMEM_BUDGET)
     for d in [54, 128, 512, 2048]:
         bi, bj = choose_blocks(8192, 8192, d)
         assert tile_vmem_bytes(bi, bj, d) <= VMEM_BUDGET

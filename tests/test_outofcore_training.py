@@ -92,9 +92,9 @@ def test_block_step_compiles_once_across_datasets():
         lambda cfg, xi, yi, xj, aj, n: dsekl.grad_block(cfg, xi, yi, xj,
                                                         aj, n),
         static_argnames=("cfg", "n"))
-    core_p = jax.jit(
-        lambda cfg, xi, yi, xjk, ajk, n: dsekl.grad_block_parallel(
-            cfg, xi, yi, xjk, ajk, n),
+    core_p = jax.jit(                   # Alg. 2: the flattened worker union
+        lambda cfg, xi, yi, xjk, ajk, n: dsekl.grad_block(
+            cfg, xi, yi, xjk.reshape(-1, xjk.shape[-1]), ajk.reshape(-1), n),
         static_argnames=("cfg", "n"))
     for n in (128, 4096, 262_144):
         ks = jax.random.split(jax.random.PRNGKey(n), 4)

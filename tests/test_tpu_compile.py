@@ -123,7 +123,7 @@ def test_gradient_step_core_compiles(one_chip, d):
     """The jitted block-gradient core of a training step (what every plan
     runs), at n_grad = n_expand = 1024, reaches the fused train pass."""
     cfg = dsekl.DSEKLConfig(n_grad=1024, n_expand=1024, impl="pallas")
-    _compile(lambda xi, yi, xj, aj: dsekl.grad_block(cfg, xi, yi, xj, aj),
+    _compile(lambda xi, yi, xj, aj: dsekl.grad_block(cfg, xi, yi, xj, aj)[0],
              _f32(one_chip, 1024, d), _f32(one_chip, 1024),
              _f32(one_chip, 1024, d), _f32(one_chip, 1024))
 
